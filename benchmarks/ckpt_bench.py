@@ -28,7 +28,6 @@ resume, byte-equal params — lives in ``tests/test_resume.py`` and the
 """
 from __future__ import annotations
 
-import dataclasses
 import json
 import os
 import tempfile
@@ -40,18 +39,12 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager
 from repro.core import (
     CheckpointSpec, DistConfig, TrainPlan, averaging, build_trainer,
-    local_steps,
+    enable_compilation_cache, local_steps,
 )
 from repro.graph import sbm_graph
 from repro.models.gnn import build_model
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_ckpt.json")
-
-# jax initializes the persistent compilation cache once per process, so
-# every measurement (including the fresh-seed remeasure) must point at the
-# SAME directory — a later config update is silently ignored
-_JIT_CACHE = os.environ.get("REPRO_COMPILE_CACHE_DIR") or tempfile.mkdtemp(
-    prefix="ckpt-bench-jit-")
 
 
 def _state_tree(mb: float = 2.0, seed: int = 0) -> Dict:
@@ -138,10 +131,7 @@ def _measure_round_times(seed: int, reps: int, r_short: int,
                                 keep=2, async_=(variant == "async"))
         return TrainPlan(phases=(local_steps(), averaging()),
                          name=f"ckpt-bench-{variant}", seed=seed,
-                         checkpoint=ck,
-                         **{**specs,
-                            "compile": dataclasses.replace(
-                                specs["compile"], cache_dir=_JIT_CACHE)})
+                         checkpoint=ck, **specs)
 
     variants = ("none", "async", "sync")
     trainers = {(v, r): build_trainer(data, model, plan_for(r, v))
@@ -191,6 +181,7 @@ def _bench_round_overhead(reps: int = 4, r_short: int = 3,
 
 
 def bench_all() -> Dict:
+    enable_compilation_cache()
     result = {"checkpoint_overhead": {
         "save_stall": _bench_save_stall(),
         "round_overhead": _bench_round_overhead(),

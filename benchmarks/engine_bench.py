@@ -66,13 +66,6 @@ A fourth section covers the train→serve path and is written to
 A fifth section covers the TrainPlan API redesign and is folded into
 ``BENCH_engine.json``:
 
-* ``compile_cache`` — cold-vs-warm compile time per plan through
-  ``CompileSpec(cache_dir=...)``: the same tiny LLCG plan run in two fresh
-  subprocesses sharing one ``jax.experimental.compilation_cache``
-  directory (``REPRO_COMPILE_CACHE_DIR`` or a tempdir), so the second
-  process restores every compiled executable from disk — the CI bench job
-  uploads that directory as an artifact.
-
 * ``plan`` — plan-lowering overhead: the declarative ``TrainPlan`` path
   (``build_trainer(...).run()``) vs driving the engine directly with a
   context/program/``run_schedule`` loop and no plan machinery (the
@@ -95,7 +88,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import DistConfig, EngineConfig, RoundInputs, RoundProgram
+from repro.core import (
+    DistConfig, EngineConfig, RoundInputs, RoundProgram,
+    enable_compilation_cache,
+)
 from repro.core.strategies import _Context, GGSContext, run_llcg
 from repro.data.graph_loader import sample_round
 from repro.graph import sbm_graph
@@ -351,78 +347,6 @@ def _bench_device_sampler(num_machines=256, local_k=1, num_nodes=4096,
         "overlap_efficiency": overlap_eff,
         "host_rounds_per_s": 1.0 / host_s,
         "device_rounds_per_s": 1.0 / dev_s,
-    }
-
-
-_CACHE_CHILD = r'''
-import json, sys, time
-import jax
-from repro.core import CompileSpec, DistConfig, build_trainer, llcg_plan
-from repro.core.plan import TrainPlan
-import dataclasses
-from repro.graph import sbm_graph
-from repro.models.gnn import build_model
-
-cache_dir = sys.argv[1]
-data = sbm_graph(num_nodes=160, num_classes=3, feature_dim=8,
-                 feature_snr=0.3, homophily=0.95, seed=0)
-model = build_model("GG", data.feature_dim, data.num_classes, hidden_dim=16)
-plan = llcg_plan(DistConfig(num_machines=2, rounds=2, local_k=2,
-                            batch_size=8, server_batch_size=16, fanout=5,
-                            partition_method="random", seed=0))
-plan = dataclasses.replace(plan,
-                           compile=CompileSpec(cache_dir=cache_dir))
-t0 = time.perf_counter()
-build_trainer(data, model, plan).run()
-print(json.dumps({"run_s": time.perf_counter() - t0}))
-'''
-
-
-def _bench_compile_cache(reps: int = 1) -> Dict:
-    """Cold-vs-warm plan compile time through the persistent cache.
-
-    Two fresh interpreter processes run the SAME tiny LLCG plan with
-    ``CompileSpec(cache_dir=...)`` pointed at one shared directory
-    (``REPRO_COMPILE_CACHE_DIR`` when set — the CI bench job persists and
-    uploads it — else a tempdir): the first pays XLA compilation and
-    populates the cache, the second restores every executable from disk.
-    """
-    import subprocess
-    import sys
-    import tempfile
-    cache_dir = os.environ.get("REPRO_COMPILE_CACHE_DIR")
-    if cache_dir:
-        os.makedirs(cache_dir, exist_ok=True)
-        cleanup = None
-    else:
-        tmp = tempfile.TemporaryDirectory(prefix="repro_jit_cache_")
-        cache_dir, cleanup = tmp.name, tmp
-    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
-        [os.path.join(os.path.dirname(__file__), "..", "src")]
-        + os.environ.get("PYTHONPATH", "").split(os.pathsep)))
-
-    def child() -> float:
-        out = subprocess.run([sys.executable, "-c", _CACHE_CHILD, cache_dir],
-                             capture_output=True, text=True, env=env)
-        if out.returncode != 0:
-            raise RuntimeError(f"cache child failed:\n{out.stderr[-2000:]}")
-        return json.loads(out.stdout.strip().splitlines()[-1])["run_s"]
-
-    was_warm = bool(os.listdir(cache_dir))
-    cold_s = child()                  # populates (or reuses) the cache
-    warm_s = child()                  # restores compiled executables
-    entries = len(os.listdir(cache_dir))
-    if cleanup is not None:
-        cleanup.cleanup()
-    return {
-        "cache_dir_preexisting": was_warm,
-        "cold_run_s": cold_s,
-        "warm_run_s": warm_s,
-        "compile_time_saved_s": cold_s - warm_s,
-        "warm_over_cold": warm_s / cold_s,
-        "cache_entries": entries,
-        "cache_dir_from_env": bool(os.environ.get(
-            "REPRO_COMPILE_CACHE_DIR")),
     }
 
 
@@ -922,12 +846,12 @@ def _bench_plan_lowering(num_machines=2, local_k=4, rounds=60,
 
 def rows() -> List[Dict]:
     """CSV rows for benchmarks.run; writes BENCH_engine/BENCH_sampler.json."""
+    enable_compilation_cache()
     # plan gate first: early-process timing is the least noisy (compile
     # times degrade measurably after the heavier sections run)
     plan_result = _bench_plan_lowering()
     result = _bench_round()
     result["plan"] = plan_result
-    result["compile_cache"] = _bench_compile_cache()
     with open(OUT_PATH, "w") as f:
         json.dump(result, f, indent=2)
     sampler = _bench_sampler()
@@ -986,11 +910,6 @@ def rows() -> List[Dict]:
         {"name": "sampler_host_many_machines",
          "us_per_call": device["host_s_per_round"] * 1e6,
          "derived": f"rounds_per_s={device['host_rounds_per_s']:.1f}"},
-        {"name": "plan_compile_cache_warm",
-         "us_per_call": result["compile_cache"]["warm_run_s"] * 1e6,
-         "derived": (f"cold={result['compile_cache']['cold_run_s']:.2f}s;"
-                     f"saved="
-                     f"{result['compile_cache']['compile_time_saved_s']:.2f}s")},
         {"name": "plan_api_vs_legacy",
          "us_per_call": result["plan"]["plan_s_per_run"] * 1e6,
          "derived": (f"overhead={result['plan']['overhead']:.3f}x(≤1.05);"

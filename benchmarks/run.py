@@ -41,6 +41,9 @@ def main(argv=None) -> None:
 
     from benchmarks import paper_experiments as P
     from benchmarks import kernel_bench as K
+    from repro.core import enable_compilation_cache
+
+    enable_compilation_cache()
 
     t0 = time.time()
     print("name,us_per_call,derived")

@@ -35,7 +35,7 @@ Wire formats priced by :func:`wire_row_bytes` / :func:`averaging_payload_bytes`:
 =========  =============================================================
 
 The quantize/dequantize ops are the Pallas tile kernels in
-:mod:`repro.kernels.quantize` (interpret mode on this container), with the
+:mod:`repro.kernels.quantize` (interpreted on the CPU backend), with the
 jnp oracles in :mod:`repro.kernels.ref` defining the semantics.
 """
 from __future__ import annotations

@@ -65,6 +65,7 @@ from __future__ import annotations
 import dataclasses
 import inspect
 import json
+import time
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
@@ -105,17 +106,22 @@ class History:
         """JSON-able snapshot for checkpoint manifests.
 
         Non-serializable ``meta`` entries are dropped (they are
-        reconstructed by the resuming trainer); the per-round series are
+        reconstructed by the resuming trainer), and so is the wall-clock
+        ``round_seconds`` series, which is no training state and would make
+        identical runs write different manifests; the per-round series are
         kept verbatim — JSON round-trips Python floats exactly, which is
         what keeps ``bytes_cum`` accumulation bit-identical across resume.
+        Every value is a copy: an asynchronous checkpoint writer serializes
+        the snapshot after later rounds have appended to ``meta``'s lists.
         """
         meta = {}
         for k, v in self.meta.items():
+            if k == "round_seconds":
+                continue
             try:
-                json.dumps(v)
+                meta[k] = json.loads(json.dumps(v))
             except (TypeError, ValueError):
                 continue
-            meta[k] = v
         return {"strategy": self.strategy, "rounds": list(self.rounds),
                 "steps_cum": list(self.steps_cum),
                 "val_score": list(self.val_score),
@@ -441,7 +447,6 @@ class RoundProgram:
 
         # shard_map backend: same per-machine body, one device per machine.
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
 
         def masked_mean_1d(losses, svalid):
             """Per-shard variant of ``masked_mean``: losses are (K,), no
@@ -603,9 +608,9 @@ class RoundProgram:
                         P())
             out_specs = (P(), P(), P())
             shard_body = shard_sync
-        self._round = self._jit_counting(shard_map(
+        self._round = self._jit_counting(jax.shard_map(
             shard_body, mesh=self.mesh, in_specs=in_specs,
-            out_specs=out_specs, check_rep=False))
+            out_specs=out_specs, check_vma=False))
 
     # ------------------------------------------------------ correction phase
     def _build_correction(self):
@@ -825,8 +830,10 @@ def run_schedule(program: RoundProgram, init_params, feats, labels,
 
     Uniform per-round metrics land in ``meta``: ``local_loss`` (every
     round), ``corr_loss`` + ``corr_rounds`` (rounds where a server
-    correction actually ran), and ``masked_steps``/``num_retraces`` are
-    always present (0 / program count when unbucketed).
+    correction actually ran), ``round_seconds`` (host wall time from the
+    round's start to its evaluated result — the first round's includes
+    compilation), and ``masked_steps``/``num_retraces`` are always present
+    (0 / program count when unbucketed).
 
     With a ``bucketing`` policy, each round's inputs are padded to the
     bucketed scan length and the tail runs as masked no-op steps — host
@@ -878,6 +885,7 @@ def run_schedule(program: RoundProgram, init_params, feats, labels,
     hist.meta.setdefault("local_loss", [])
     hist.meta.setdefault("corr_loss", [])
     hist.meta.setdefault("corr_rounds", [])
+    hist.meta.setdefault("round_seconds", [])
     bytes_cum = float(hist.bytes_cum[-1]) if hist.bytes_cum else 0.0
     steps_cum = int(hist.steps_cum[-1]) if hist.steps_cum else 0
 
@@ -892,6 +900,7 @@ def run_schedule(program: RoundProgram, init_params, feats, labels,
     for r, k in enumerate(schedule, start=1):
         if r < start:
             continue
+        t0 = time.perf_counter()
         inputs = pending if prefetch else draw(r, k)
         state, metrics = program.run_round(state, feats, labels, inputs)
         if checkpoint_hook is not None:
@@ -911,6 +920,7 @@ def run_schedule(program: RoundProgram, init_params, feats, labels,
         bytes_cum += bpr(r, k)
         steps_cum += spr(r, k)
         loss, score = evaluate(state.params)
+        hist.meta["round_seconds"].append(time.perf_counter() - t0)
         hist.rounds.append(r)
         hist.steps_cum.append(steps_cum)
         hist.val_score.append(score)

@@ -63,7 +63,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import hashlib
-import warnings
+import os
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -288,18 +288,14 @@ class ScheduleSpec:
 class CompileSpec:
     """Tracing/compatibility knobs (no effect on the math).
 
-    ``cache_dir`` opts into jax's persistent compilation cache
-    (:mod:`jax.experimental.compilation_cache`): compiled executables are
-    written under the directory and later runs — including fresh
-    processes, e.g. CI bench jobs restoring the dir as an artifact — skip
-    XLA compilation for already-seen (program, shape) pairs.
+    The persistent compilation cache is process-global, not per plan: see
+    :func:`enable_compilation_cache`.
     """
 
     rng_compat: bool = False         # replay the pre-vectorization RNG
     k_bucketing: bool = False        # pad K to buckets → O(log) retraces
     bucket_growth: int = 2
     bucket_mode: str = "geometric"
-    cache_dir: Optional[str] = None  # persistent compilation cache (opt-in)
 
     def __post_init__(self):
         _check(self.bucket_growth >= 2, "bucket_growth must be ≥ 2")
@@ -348,23 +344,30 @@ class CheckpointSpec:
         _check(self.queue_size >= 1, "CheckpointSpec.queue_size must be ≥ 1")
 
 
-def enable_compilation_cache(cache_dir: str) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir``.
+#: The compilation cache's directory when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: one fixed path inside the checkout.  The path is part of the
+#: cache key, so a temporary or per-run directory would never hit.
+DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), *[os.pardir] * 3,
+    ".jax_cache")
 
-    Idempotent and process-global (the cache is a jax config, not a
-    per-plan object).  The size/time floors are zeroed so even the small
-    CPU-test programs are cached — the point here is cold-vs-warm compile
-    accounting and CI artifact reuse, not disk economy.  Returns False
-    (with a warning) on jax builds without persistent-cache support.
+
+def enable_compilation_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the directory (jax reads the
+    variable itself, and no other is set here); otherwise
+    :data:`DEFAULT_COMPILATION_CACHE_DIR`.  Process-global: call it before
+    the first compile, since jax initialises the cache once per process.
+    The size/time floors are zeroed so every program is cached.
     """
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception as e:  # pragma: no cover - depends on jax build
-        warnings.warn(f"persistent compilation cache unavailable: {e}")
-        return False
-    return True
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.normpath(DEFAULT_COMPILATION_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path
 
 
 # --------------------------------------------------------------------------
@@ -583,6 +586,11 @@ class RoundSampler:
         self.batch_size = loc.batch_size
         self.placement = smp.placement
         self.mesh = mesh
+        self._machine_sharding = None
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            self._machine_sharding = NamedSharding(mesh,
+                                                   PartitionSpec("machine"))
         self.partition = partition_graph(data.graph, comm.num_machines,
                                          method=comm.partition_method,
                                          seed=plan.seed)
@@ -607,8 +615,8 @@ class RoundSampler:
             self.feats[p, :nl] = self.loaders[p].features
             self.labels[p, :nl] = self.loaders[p].labels
             self.n_local[p] = nl
-        self.feats_j = jnp.asarray(self.feats)
-        self.labels_j = jnp.asarray(self.labels)
+        self.feats_j = self._per_machine(self.feats)
+        self.labels_j = self._per_machine(self.labels)
 
         self.opt = make_optimizer(loc.optimizer, loc.lr)
         self.step = make_machine_step(model, self.opt)
@@ -666,6 +674,14 @@ class RoundSampler:
     def num_sampler_retraces(self) -> int:
         return self._sampler_traces.count_value
 
+    def _per_machine(self, x) -> jnp.ndarray:
+        """``x`` (leading machine axis) on the device.  With a mesh, one
+        machine's slice per device of its ``('machine',)`` axis, so no
+        round re-shards it from a single device."""
+        if self._machine_sharding is None:
+            return jnp.asarray(x)
+        return jax.device_put(x, self._machine_sharding)
+
     # ----------------------------------------------------------- rng snapshot
     def snapshot(self) -> Dict:
         """JSON-able position of every host RNG stream (for exact resume).
@@ -700,10 +716,7 @@ class RoundSampler:
         dcsr = self._device_csrs.get(kind)
         if dcsr is not None:
             return dcsr
-        sharding = None
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-            sharding = NamedSharding(self.mesh, PartitionSpec("machine"))
+        sharding = self._machine_sharding
         if kind == "local":
             dcsr = build_device_csr(
                 [ld.sampler.graph for ld in self.loaders], n_pad=self.n_max,
@@ -821,10 +834,10 @@ class RoundSampler:
         self.exchange_bytes_per_step = self.halo_program.exchange_bytes(
             d, dtype=fdtype, compression=halo_comp)
         self.halo_inputs = dict(
-            halo_send_idx=jnp.asarray(self.halo_program.send_idx),
-            halo_recv_idx=jnp.asarray(self.halo_program.recv_idx),
-            halo_dest_idx=jnp.asarray(self.halo_program.dest_idx),
-            halo_recv_valid=jnp.asarray(self.halo_program.recv_valid))
+            halo_send_idx=self._per_machine(self.halo_program.send_idx),
+            halo_recv_idx=self._per_machine(self.halo_program.recv_idx),
+            halo_dest_idx=self._per_machine(self.halo_program.dest_idx),
+            halo_recv_valid=self._per_machine(self.halo_program.recv_valid))
         self._halo_built = True
 
     # ---------------------------------------------------------------- local
@@ -984,11 +997,11 @@ class RoundSampler:
         halo = {}
         if desc.kind == "ext" and desc.mode == "halo":
             halo = self.halo_inputs
-        return RoundInputs(tables=jnp.asarray(tables),
-                           masks=jnp.asarray(masks),
-                           batches=jnp.asarray(batches),
-                           bmasks=jnp.asarray(bmasks), step_valid=svalid,
-                           **corr, **halo)
+        return RoundInputs(tables=self._per_machine(tables),
+                           masks=self._per_machine(masks),
+                           batches=self._per_machine(batches),
+                           bmasks=self._per_machine(bmasks),
+                           step_valid=svalid, **corr, **halo)
 
     def round_feats_labels(self, kind: str) -> Tuple[Any, Any]:
         """The (feats, labels) device arrays a round kind trains on."""
@@ -998,7 +1011,8 @@ class RoundSampler:
             self.ensure_halo()
             feats = (self.ext_feats if self.plan.comm.host_halo
                      else self.local_feats)
-            return jnp.asarray(feats), jnp.asarray(self.ext_labels)
+            return self._per_machine(feats), self._per_machine(
+                self.ext_labels)
         if kind == "full":
             return self.full_feats[None], self.full_labels[None]
         raise ValueError(f"unknown round kind {kind!r}")
@@ -1309,8 +1323,6 @@ class PlanTrainer:
         # deliberately locals, not attributes: a finished trainer must not
         # pin the padded feature copies + jit caches in memory (sweeps hold
         # many PlanTrainer objects)
-        if plan.compile.cache_dir is not None:
-            enable_compilation_cache(plan.compile.cache_dir)
         sampler = RoundSampler(data, model, plan, mesh=self.mesh)
         if any(d.kind == "ext" for d in self.descs):
             sampler.ensure_halo()

@@ -9,8 +9,8 @@
 * :mod:`repro.kernels.quantize`     — row-wise stochastic-rounding int8
   quantize/dequantize (the compressed-communication wire format).
 * :mod:`repro.kernels.ref`          — pure-jnp oracles for all of the above.
-* :mod:`repro.kernels.ops`          — jit'd public wrappers with auto
-  interpret-mode fallback on CPU.
+* :mod:`repro.kernels.ops`          — jit'd public wrappers; the kernels
+  run interpreted on the CPU backend and compiled on a TPU.
 
 All kernels use explicit BlockSpec VMEM tiling with (8,128)-aligned blocks
 and are validated against the oracles in interpret mode (tests sweep shapes

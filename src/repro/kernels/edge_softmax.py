@@ -30,13 +30,16 @@ def _edge_softmax_kernel(scores_ref, mask_ref, vals_ref, out_ref):
     denom = jnp.clip(jnp.sum(e, axis=-1, keepdims=True), 1e-30, None)
     alpha = e / denom                                # (BN, F)
     v = vals_ref[...].astype(jnp.float32)            # (BN, F, BD)
-    out_ref[...] = jnp.einsum("nf,nfd->nd", alpha, v)
+    # broadcast-multiply + sum over F, not a batched dot_general: Mosaic
+    # has no lowering for a dot with a batch dim here, and with F ≤ a few
+    # dozen the contraction is VPU work either way
+    out_ref[...] = jnp.sum(alpha[:, :, None] * v, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_d", "interpret"))
 def edge_softmax(scores: jnp.ndarray, mask: jnp.ndarray, vals: jnp.ndarray,
-                 block_n: int = 128, block_d: int = 128,
-                 interpret: bool = True) -> jnp.ndarray:
+                 *, interpret: bool, block_n: int = 128,
+                 block_d: int = 128) -> jnp.ndarray:
     """out[n] = Σ_f softmax_f(scores[n,·])·vals[n,f,:], masked.
 
     scores/mask: (N, F); vals: (N, F, D).  N % block_n == 0, D % block_d == 0

@@ -51,7 +51,12 @@ def _make_scan_kernel(strict: bool):
         lw = lw_ref[0].astype(jnp.float32)        # (L, dk)
         L = q.shape[0]
 
-        lw_cum = jnp.cumsum(lw, axis=0)           # log P_t
+        # log P_t as a lower-triangular matmul: Mosaic has no cumsum
+        row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+        tril = jnp.where(row >= col, 1.0, 0.0).astype(jnp.float32)
+        lw_cum = jnp.dot(tril, lw, preferred_element_type=jnp.float32,
+                         precision=jax.lax.Precision.HIGHEST)
         p = jnp.exp(lw_cum)
         pinv = jnp.exp(-lw_cum)
         # strict: the query sees h_{t-1} ⇒ decay product P_{t-1}
@@ -61,20 +66,21 @@ def _make_scan_kernel(strict: bool):
 
         h_in = h_scr[...]                         # (dk, dv)
         attn = jnp.dot(qp, kp.T, preferred_element_type=jnp.float32)  # (L,L)
-        row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
         attn = jnp.where(row > col if strict else row >= col, attn, 0.0)
         y = jnp.dot(attn, v, preferred_element_type=jnp.float32)
         y += jnp.dot(qp, h_in, preferred_element_type=jnp.float32)
         if strict:
-            u = u_ref[0].astype(jnp.float32)      # (dk,)
-            bonus = jnp.sum(q * u[None, :] * k, axis=1)   # (L,)
-            y += bonus[:, None] * v
+            u = u_ref[0].astype(jnp.float32)      # (1, dk)
+            bonus = jnp.sum(q * u * k, axis=1, keepdims=True)   # (L, 1)
+            y += bonus * v
         y_ref[0] = y
 
-        p_last = p[-1]                            # (dk,)
-        h_out = p_last[:, None] * h_in + jnp.dot(
-            (kp * p_last[None, :]).T, v, preferred_element_type=jnp.float32)
+        # P_L as a row (scales K) and as a column (scales h's dk rows),
+        # reduced directly: Mosaic lowers neither p[-1] nor a 1-D transpose
+        p_row = jnp.exp(jnp.sum(lw, axis=0, keepdims=True))     # (1, dk)
+        p_col = jnp.exp(jnp.sum(lw.T, axis=1, keepdims=True))   # (dk, 1)
+        h_out = p_col * h_in + jnp.dot(
+            (kp * p_row).T, v, preferred_element_type=jnp.float32)
         h_scr[...] = h_out
 
         @pl.when(c == n_chunks - 1)
@@ -89,7 +95,7 @@ def _make_scan_kernel(strict: bool):
 def linear_scan_chunked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         log_w: jnp.ndarray, h0: jnp.ndarray,
                         u: jnp.ndarray | None = None,
-                        chunk: int = 64, interpret: bool = True,
+                        *, interpret: bool, chunk: int = 64,
                         strict: bool = False):
     """Batched chunked scan.
 
@@ -103,6 +109,9 @@ def linear_scan_chunked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     n_chunks = t // chunk
     if u is None:
         u = jnp.zeros((bh, dk), jnp.float32)
+    # (BH, 1, dk): a (1, dk) block of a 2-D (BH, dk) operand is neither
+    # (8, 128)-aligned nor full-extent, which Mosaic refuses
+    u = u.reshape(bh, 1, dk)
 
     grid = (bh, n_chunks)
     y, hT = pl.pallas_call(
@@ -114,7 +123,7 @@ def linear_scan_chunked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pl.BlockSpec((1, chunk, dv), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, dk), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, dk, dv), lambda b, c: (b, 0, 0)),
-            pl.BlockSpec((1, dk), lambda b, c: (b, 0)),
+            pl.BlockSpec((1, 1, dk), lambda b, c: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, dv), lambda b, c: (b, c, 0)),

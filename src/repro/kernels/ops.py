@@ -1,20 +1,18 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Every op takes unpadded, natural-layout inputs, handles padding/alignment,
-and dispatches to the kernel (``interpret=True`` on CPU — the container has
-no TPU — compiled on real hardware via ``interpret=False``).  The matching
-oracle from :mod:`repro.kernels.ref` defines the semantics; ``use_ref=True``
-forces the oracle path (used by equivalence tests and as an escape hatch).
+and dispatches to the kernel: interpreted on the CPU backend, compiled
+everywhere else (see :func:`pallas_interpret`).  The matching oracle from
+:mod:`repro.kernels.ref` defines the semantics; ``use_ref=True`` forces the
+oracle path (used by equivalence tests and as an escape hatch) — no op
+swaps it in without being asked.
 """
 from __future__ import annotations
 
-import functools
-import os
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.graph.csr import CSRGraph
 from repro.kernels import ref
@@ -23,16 +21,15 @@ from repro.kernels.linear_scan import linear_scan_chunked
 from repro.kernels.quantize import dequantize_rows, quantize_rows
 from repro.kernels.spmm import build_bcsr, spmm_bcsr
 
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
-# REPRO_PALLAS_COMPILED=1 forces compiled Pallas lowering off-TPU (real
-# hardware without auto-detection, or Mosaic-capable backends); the default
-# on this CPU container is interpret mode.
-_INTERPRET = not (_ON_TPU or os.environ.get("REPRO_PALLAS_COMPILED") == "1")
-
 
 def pallas_interpret() -> bool:
-    """Whether the Pallas kernels run in interpret mode on this host."""
-    return _INTERPRET
+    """Whether Pallas kernels run in interpret mode: on the CPU backend only.
+
+    Decided at call (trace) time from ``jax.default_backend()``, so
+    importing this module initialises no backend, and a TPU never runs the
+    interpreter.
+    """
+    return jax.default_backend() == "cpu"
 
 
 def _pad_to(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
@@ -86,7 +83,7 @@ def spmm_aggregate(graph: CSRGraph, h: jnp.ndarray,
         out = ref.spmm_bcsr_ref(tile_cols, tile_vals, h_pad)
     else:
         out = spmm_bcsr(tile_cols, tile_vals, h_pad,
-                        block_d=block_d, interpret=_INTERPRET)
+                        block_d=block_d, interpret=pallas_interpret())
     return out[:n, :d].astype(h.dtype)
 
 
@@ -110,7 +107,8 @@ def edge_softmax_aggregate(scores: jnp.ndarray, mask: jnp.ndarray,
     s = _pad_to(scores, 0, bn)
     m = _pad_to(mask, 0, bn)
     v = _pad_to(_pad_to(vals, 0, bn), 2, bd)
-    out = edge_softmax(s, m, v, block_n=bn, block_d=bd, interpret=_INTERPRET)
+    out = edge_softmax(s, m, v, block_n=bn, block_d=bd,
+                       interpret=pallas_interpret())
     return out[:n, :d].astype(vals.dtype)
 
 
@@ -158,7 +156,8 @@ def quantize_int8_rows(x: jnp.ndarray, u: Optional[jnp.ndarray] = None,
     br = min(block_r, max(8, 1 << (r - 1).bit_length()))
     xp = _pad_to(x.astype(jnp.float32), 0, br)
     up = _pad_to(u.astype(jnp.float32), 0, br)
-    vals, scale = quantize_rows(xp, up, block_r=br, interpret=_INTERPRET)
+    vals, scale = quantize_rows(xp, up, block_r=br,
+                                interpret=pallas_interpret())
     return vals[:r], scale[:r]
 
 
@@ -172,7 +171,8 @@ def dequantize_int8_rows(vals: jnp.ndarray, scale: jnp.ndarray,
     br = min(block_r, max(8, 1 << (r - 1).bit_length()))
     vp = _pad_to(vals, 0, br)
     sp = _pad_to(scale.astype(jnp.float32), 0, br)
-    return dequantize_rows(vp, sp, block_r=br, interpret=_INTERPRET)[:r]
+    return dequantize_rows(vp, sp, block_r=br,
+                           interpret=pallas_interpret())[:r]
 
 
 # --------------------------------------------------------------------------
@@ -187,16 +187,21 @@ def linear_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     q,k,log_w: (BH, T, dk); v: (BH, T, dv).  ``strict``/``u`` select the
     RWKV6 output convention (y_t reads h_{t−1} + u-bonus).  Returns (y, h_T).
+    A T that is not a multiple of ``chunk`` is zero-padded at the end: the
+    padded steps carry k = v = 0 and decay 1, so they leave h_T and the
+    real y_t unchanged.
     """
     bh, t, dk = q.shape
     dv = v.shape[-1]
     if h0 is None:
         h0 = jnp.zeros((bh, dk, dv), jnp.float32)
-    if use_ref or t % chunk != 0:
+    if use_ref:
         if strict:
             from repro.models.transformer.scan_common import chunked_scan
             return chunked_scan(q, k, v, log_w, h0, chunk=chunk,
                                 strict=True, u=u)
         return ref.linear_scan_batched_ref(q, k, v, log_w, h0)
-    return linear_scan_chunked(q, k, v, log_w, h0, u=u, chunk=chunk,
-                               interpret=_INTERPRET, strict=strict)
+    q, k, v, log_w = (_pad_to(x, 1, chunk) for x in (q, k, v, log_w))
+    y, h_t = linear_scan_chunked(q, k, v, log_w, h0, u=u, chunk=chunk,
+                                 interpret=pallas_interpret(), strict=strict)
+    return y[:, :t], h_t

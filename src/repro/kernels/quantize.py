@@ -37,8 +37,8 @@ def _dequantize_kernel(vals_ref, scale_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
-def quantize_rows(x: jnp.ndarray, u: jnp.ndarray, block_r: int = 128,
-                  interpret: bool = True
+def quantize_rows(x: jnp.ndarray, u: jnp.ndarray, *, interpret: bool,
+                  block_r: int = 128
                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """(q int8 (R, C), scale f32 (R, 1)) ← x (R, C), u (R, C) uniforms.
 
@@ -68,8 +68,8 @@ def quantize_rows(x: jnp.ndarray, u: jnp.ndarray, block_r: int = 128,
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
-def dequantize_rows(vals: jnp.ndarray, scale: jnp.ndarray,
-                    block_r: int = 128, interpret: bool = True) -> jnp.ndarray:
+def dequantize_rows(vals: jnp.ndarray, scale: jnp.ndarray, *,
+                    interpret: bool, block_r: int = 128) -> jnp.ndarray:
     """f32 (R, C) ← vals int8 (R, C) · scale f32 (R, 1).  R % block_r == 0."""
     r, c = vals.shape
     assert r % block_r == 0

@@ -106,7 +106,7 @@ def _spmm_kernel(cols_ref, vals_ref, h_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def spmm_bcsr(tile_cols: jnp.ndarray, tile_vals: jnp.ndarray, h: jnp.ndarray,
-              block_d: int = 128, interpret: bool = True) -> jnp.ndarray:
+              *, interpret: bool, block_d: int = 128) -> jnp.ndarray:
     """Â @ H over the BCSR layout.  h: (n_pad, D) with D % block_d == 0."""
     n_rb, max_t, bm, bn = tile_vals.shape
     n_pad, d = h.shape

@@ -76,18 +76,9 @@ _PAIRS_RE = re.compile(r"source_target_pairs=\{\{(\d+),(\d+)\}")
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` as a flat dict across jax versions.
-
-    Older jax returns a one-element list of per-computation dicts, newer
-    returns the dict directly; both normalize to ``{}`` when unavailable.
-    """
-    try:
-        cost = compiled.cost_analysis()
-    except Exception:  # noqa: BLE001
-        return {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost) if cost else {}
+    """``Compiled.cost_analysis()`` as a flat dict (``{}`` when the backend
+    reports none)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def _first_group(line: str):

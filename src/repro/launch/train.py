@@ -204,6 +204,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
     cfg = TrainConfig(**{f.name: getattr(args, f.name)
                          for f in dataclasses.fields(TrainConfig)})
+    from repro.core.plan import enable_compilation_cache
+    enable_compilation_cache()
     train(cfg)
 
 

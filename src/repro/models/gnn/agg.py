@@ -23,8 +23,8 @@ baked-in lowering:
     (:func:`repro.kernels.spmm.spmm_bcsr`) with an unnormalized-adjacency
     operand (symmetric, so the ``custom_vjp`` backward reuses the same
     tiles); the GAT softmax-aggregate routes through the fused Pallas
-    edge-softmax kernel.  ``interpret=True`` on this CPU container,
-    ``REPRO_PALLAS_COMPILED=1`` flips to compiled on real hardware.
+    edge-softmax kernel; interpreted on the CPU backend, compiled on a
+    TPU (:func:`repro.kernels.ops.pallas_interpret`).
 
 ``layout="auto"``
     :func:`choose_layout` picks per (graph, table width, sampling) via a

@@ -28,7 +28,7 @@ def chunked_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     Returns (y (BH,T,dv) f32, h_T (BH,dk,dv) f32).
     """
-    if use_pallas and q.shape[1] % chunk == 0:
+    if use_pallas:
         from repro.kernels.ops import linear_scan
         return linear_scan(q, k, v, log_w, h0, chunk=chunk, strict=strict,
                            u=u)

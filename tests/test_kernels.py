@@ -11,7 +11,9 @@ except ModuleNotFoundError:  # deterministic fallback, see hypothesis_compat
 from repro.graph import sbm_graph, rmat_graph
 from repro.graph.csr import build_neighbor_table
 from repro.kernels import ref
-from repro.kernels.ops import spmm_aggregate, edge_softmax_aggregate, linear_scan
+from repro.kernels.ops import (
+    edge_softmax_aggregate, linear_scan, pallas_interpret, spmm_aggregate,
+)
 from repro.kernels.spmm import build_bcsr, spmm_bcsr
 from repro.models.gnn.layers import mean_aggregate
 
@@ -37,7 +39,8 @@ def test_spmm_bcsr_matches_dense(norm):
                                    normalization=norm)
     h = jnp.asarray(np.random.default_rng(0).standard_normal(
         (n_pad, 128)).astype(np.float32))
-    out_k = spmm_bcsr(jnp.asarray(cols), jnp.asarray(vals), h, block_d=128)
+    out_k = spmm_bcsr(jnp.asarray(cols), jnp.asarray(vals), h, block_d=128,
+                      interpret=pallas_interpret())
     out_r = ref.spmm_bcsr_ref(jnp.asarray(cols), jnp.asarray(vals), h)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                rtol=1e-5, atol=1e-5)
@@ -99,7 +102,7 @@ def test_edge_softmax_dtypes(dtype):
 # --------------------------------------------------------------------------
 @pytest.mark.parametrize("bh,t,dk,dv,chunk", [
     (2, 64, 8, 16, 16), (3, 128, 16, 24, 32), (1, 96, 32, 32, 32),
-    (4, 256, 64, 64, 64),
+    (4, 256, 64, 64, 64), (2, 100, 16, 16, 32),
 ])
 def test_linear_scan_kernel_matches_sequential_ref(bh, t, dk, dv, chunk):
     rng = np.random.default_rng(bh + t)

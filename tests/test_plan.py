@@ -422,3 +422,48 @@ print(json.dumps(out))
     assert out["hybrid"]["kinds"] == ["ext", "ext", "local", "local"]
     assert "ext" in out["switch"]["kinds"] and \
         "local" in out["switch"]["kinds"]
+
+
+# --------------------------------------------------------------------------
+# 6. persistent compilation cache: one fixed directory per process
+# --------------------------------------------------------------------------
+_CACHE_CHILD = r"""
+import json, jax, jax.numpy as jnp
+from repro.core import enable_compilation_cache
+hits = []
+jax.monitoring.register_event_listener(
+    lambda e, **_: hits.append(e) if e.endswith("/cache_hits") else None)
+path = enable_compilation_cache()
+if __import__("sys").argv[1] == "compile":
+    jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()
+print(json.dumps({"path": path, "config": jax.config.jax_compilation_cache_dir,
+                  "hits": len(hits)}))
+"""
+
+
+def _cache_child(mode: str, cache_env):
+    env = dict(os.environ, PYTHONPATH=SRC)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if cache_env is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(cache_env)
+    proc = subprocess.run([sys.executable, "-c", _CACHE_CHILD, mode],
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_compilation_cache_uses_env_dir_and_hits_on_rerun(tmp_path):
+    cache = tmp_path / "jax-cache"
+    first = _cache_child("compile", cache)
+    assert first["path"] == first["config"] == str(cache)
+    assert first["hits"] == 0 and os.listdir(cache)
+    assert _cache_child("compile", cache)["hits"] >= 1
+
+
+def test_compilation_cache_defaults_to_fixed_checkout_dir():
+    from repro.core.plan import DEFAULT_COMPILATION_CACHE_DIR
+    out = _cache_child("no-compile", None)
+    want = os.path.normpath(DEFAULT_COMPILATION_CACHE_DIR)
+    assert out["path"] == out["config"] == want
+    assert want == os.path.join(os.path.dirname(SRC), ".jax_cache")

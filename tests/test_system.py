@@ -49,10 +49,13 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
 import json, sys
 import jax
+from jax.sharding import AxisType
 from repro.launch.dryrun import (build_case, collective_bytes_from_hlo,
                                  cost_analysis_dict)
 from repro.configs import get_smoke_config
-mesh = jax.make_mesh((4, 4), ("data", "model"))
+# Auto axes, as repro.launch.mesh builds the production mesh
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 out = {}
 for arch in ("gemma3-1b", "qwen2-moe-a2.7b", "zamba2-7b", "rwkv6-1.6b"):
     cfg = get_smoke_config(arch)
