@@ -59,6 +59,14 @@ Host-side round inputs come from the vectorized sampler
 (:mod:`repro.graph.sampling`); its ``rng_compat=True`` knob replays the
 legacy per-node draw stream so engine trajectories can be compared
 bit-for-bit against pre-vectorization references.
+
+**Tracing.**  The local phase and the correction compile as two programs,
+``jit_counted_round*`` and ``jit_counted_correction*`` in a profiler trace,
+with the named scopes ``local_steps``, ``averaging`` and
+``correction_step`` in their op metadata.  :func:`run_schedule` marks its
+host work with :func:`span`: per round an ``llcg.round`` step span holding
+``llcg.sample``, ``llcg.dispatch``, ``llcg.read`` (each blocking read),
+``llcg.evaluate`` and ``llcg.checkpoint``.
 """
 from __future__ import annotations
 
@@ -78,6 +86,12 @@ from repro.comm.compress import (check_compression, compress_features,
 from repro.core.machine import halo_fill, make_local_round, make_loss_fn
 from repro.core.schedules import KBucketing
 from repro.optim.optimizers import Optimizer, apply_updates, masked_update
+
+
+def span(name: str) -> jax.profiler.TraceAnnotation:
+    """A host span ``llcg.<name>`` on the profiler's clock, beside the
+    device's events.  Without a running trace it records nothing."""
+    return jax.profiler.TraceAnnotation("llcg." + name)
 
 
 # --------------------------------------------------------------------------
@@ -281,12 +295,13 @@ class RoundProgram:
         a new scan length K) and never on cached dispatches.  Counting goes
         through the trace *signature* so a resumed process re-compiling a
         shape the pre-crash process already traced does not inflate the
-        run's retrace count.
+        run's retrace count.  The compiled module is named
+        ``jit_counted_round``.
         """
-        def counted(*args):
+        def counted_round(*args):
             self._round_traces.count(trace_signature(args))
             return fn(*args)
-        return jax.jit(counted)
+        return jax.jit(counted_round)
 
     # ----------------------------------------------------------- local phase
     def _build_round(self):
@@ -311,21 +326,22 @@ class RoundProgram:
                          batches, bmasks, svalid):
             """The K local steps per machine (vmap over P) — shared by the
             plain and the compressed averaging paths."""
-            if cfg.reset_local_opt:
-                # fresh per-round optimizer (Alg. 2 line 3): the carried
-                # opt_state is a scalar placeholder, threaded through
-                # unchanged so the round signature stays uniform
-                run = lambda f, l, t, m, b, bm: local_round(
-                    params, None, f, l, t, m, b, bm, svalid)
-                p_new, _, losses = jax.vmap(run)(feats, labels, tables,
-                                                 masks, batches, bmasks)
-                o_new = opt_state
-            else:
-                p_new, o_new, losses = jax.vmap(
-                    local_round,
-                    in_axes=(None, 0, 0, 0, 0, 0, 0, 0, None))(
-                    params, opt_state, feats, labels, tables, masks, batches,
-                    bmasks, svalid)
+            with jax.named_scope("local_steps"):
+                if cfg.reset_local_opt:
+                    # fresh per-round optimizer (Alg. 2 line 3): the carried
+                    # opt_state is a scalar placeholder, threaded through
+                    # unchanged so the round signature stays uniform
+                    run = lambda f, l, t, m, b, bm: local_round(
+                        params, None, f, l, t, m, b, bm, svalid)
+                    p_new, _, losses = jax.vmap(run)(feats, labels, tables,
+                                                     masks, batches, bmasks)
+                    o_new = opt_state
+                else:
+                    p_new, o_new, losses = jax.vmap(
+                        local_round,
+                        in_axes=(None, 0, 0, 0, 0, 0, 0, 0, None))(
+                        params, opt_state, feats, labels, tables, masks,
+                        batches, bmasks, svalid)
             return p_new, o_new, losses
 
         def round_local(params, opt_state, feats, labels, tables, masks,
@@ -335,7 +351,9 @@ class RoundProgram:
                 params, opt_state, feats, labels, tables, masks, batches,
                 bmasks, svalid)
             # Alg. 1/2 line 12 — THE inter-machine collective
-            avg = jax.tree_util.tree_map(lambda x: jnp.mean(x, axis=0), p_new)
+            with jax.named_scope("averaging"):
+                avg = jax.tree_util.tree_map(lambda x: jnp.mean(x, axis=0),
+                                             p_new)
             return avg, o_new, masked_mean(losses, svalid)
 
         def round_local_comp(params, opt_state, feats, labels, tables, masks,
@@ -359,11 +377,12 @@ class RoundProgram:
                 delta = jax.tree_util.tree_map(jnp.add, delta, residual)
             keys = (machine_keys(comm_key, cfg.num_machines) if stoch
                     else None)
-            payload, scales = compress_tree(delta, comp, key=keys,
-                                            stacked=True)
-            deq = decompress_tree(payload, scales, comp)
-            avg = jax.tree_util.tree_map(
-                lambda p0, d: p0 + jnp.mean(d, axis=0), params, deq)
+            with jax.named_scope("averaging"):
+                payload, scales = compress_tree(delta, comp, key=keys,
+                                                stacked=True)
+                deq = decompress_tree(payload, scales, comp)
+                avg = jax.tree_util.tree_map(
+                    lambda p0, d: p0 + jnp.mean(d, axis=0), params, deq)
             outs = (avg, o_new, masked_mean(losses, svalid))
             if ef:
                 outs += (jax.tree_util.tree_map(jnp.subtract, delta, deq),)
@@ -461,9 +480,10 @@ class RoundProgram:
                 o = None  # local_round re-inits from the incoming params
             else:
                 o = jax.tree_util.tree_map(lambda x: x[0], opt_state)
-            return local_round(
-                params, o, feats[0], labels[0], tables[0], masks[0],
-                batches[0], bmasks[0], svalid)
+            with jax.named_scope("local_steps"):
+                return local_round(
+                    params, o, feats[0], labels[0], tables[0], masks[0],
+                    batches[0], bmasks[0], svalid)
 
         def shard_local(params, opt_state, feats, labels, tables, masks,
                         batches, bmasks, svalid):
@@ -471,7 +491,8 @@ class RoundProgram:
             p_new, o_new, losses = _shard_local_steps(
                 params, opt_state, feats, labels, tables, masks, batches,
                 bmasks, svalid)
-            p_avg = jax.lax.pmean(p_new, "machine")
+            with jax.named_scope("averaging"):
+                p_avg = jax.lax.pmean(p_new, "machine")
             loss = jax.lax.pmean(masked_mean_1d(losses, svalid), "machine")
             if cfg.reset_local_opt:
                 o_new = opt_state  # scalar placeholder, unchanged
@@ -501,13 +522,14 @@ class RoundProgram:
             key_m = (jax.random.fold_in(comm_key,
                                         jax.lax.axis_index("machine"))
                      if stoch else None)
-            payload, scales = compress_tree(delta, comp, key=key_m)
-            g_payload = jax.lax.all_gather(payload, "machine")
-            g_scales = (jax.lax.all_gather(scales, "machine")
-                        if scales is not None else None)
-            deq_all = decompress_tree(g_payload, g_scales, comp)
-            p_avg = jax.tree_util.tree_map(
-                lambda p0, d: p0 + jnp.mean(d, axis=0), params, deq_all)
+            with jax.named_scope("averaging"):
+                payload, scales = compress_tree(delta, comp, key=key_m)
+                g_payload = jax.lax.all_gather(payload, "machine")
+                g_scales = (jax.lax.all_gather(scales, "machine")
+                            if scales is not None else None)
+                deq_all = decompress_tree(g_payload, g_scales, comp)
+                p_avg = jax.tree_util.tree_map(
+                    lambda p0, d: p0 + jnp.mean(d, axis=0), params, deq_all)
             loss = jax.lax.pmean(masked_mean_1d(losses, svalid), "machine")
             if cfg.reset_local_opt:
                 o_out = opt_state  # scalar placeholder, unchanged
@@ -635,10 +657,11 @@ class RoundProgram:
                 else:
                     batch, bmask = xs
                     table, mask = tables, masks
-                loss, grads = grad_fn(p, feats, table, mask, batch, labels,
-                                      bmask, agg)
-                upd, so = server_opt.update(grads, so, p)
-                return (apply_updates(p, upd), so), loss
+                with jax.named_scope("correction_step"):
+                    loss, grads = grad_fn(p, feats, table, mask, batch,
+                                          labels, bmask, agg)
+                    upd, so = server_opt.update(grads, so, p)
+                    return (apply_updates(p, upd), so), loss
 
             xs = ((tables, masks, batches, bmasks) if per_step_tables
                   else (batches, bmasks))
@@ -646,13 +669,13 @@ class RoundProgram:
                 one, (params, server_state), xs)
             return params, server_state, jnp.mean(losses)
 
-        def counted(*args):
+        def counted_correction(*args):
             # trace-time side effect, same discipline as _jit_counting: a
             # layout change retraces once, never per round
             self._corr_traces.count(trace_signature(args))
             return corr_scan(*args)
 
-        self._corr = jax.jit(counted)
+        self._corr = jax.jit(counted_correction)
 
     # ------------------------------------------------------------------- API
     def init_state(self, params) -> EngineState:
@@ -890,50 +913,64 @@ def run_schedule(program: RoundProgram, init_params, feats, labels,
     steps_cum = int(hist.steps_cum[-1]) if hist.steps_cum else 0
 
     def draw(r, k):
-        inputs = sample_fn(r, k)
-        if bucketing is not None:
-            inputs = pad_inputs_to_bucket(inputs, bucketing.pad_length(k))
+        with span("sample"):
+            inputs = sample_fn(r, k)
+            if bucketing is not None:
+                inputs = pad_inputs_to_bucket(inputs,
+                                              bucketing.pad_length(k))
         return inputs
+
+    def read(x) -> float:
+        with span("read"):
+            return float(x)
 
     pending = (draw(start, schedule[start - 1])
                if (prefetch and start <= len(schedule)) else None)
     for r, k in enumerate(schedule, start=1):
         if r < start:
             continue
-        t0 = time.perf_counter()
-        inputs = pending if prefetch else draw(r, k)
-        state, metrics = program.run_round(state, feats, labels, inputs)
-        if checkpoint_hook is not None:
-            # BEFORE the prefetch draw: the snapshot must capture the RNG
-            # streams at "rounds 1..r drawn, nothing beyond"
-            checkpoint_hook.after_round(r, state)
-        if prefetch:
-            # the overlap: round r's scan is in flight, nothing has blocked
-            # on it yet — issue round r+1's sample NOW
-            pending = draw(r + 1, schedule[r]) if r < len(schedule) else None
-        lloss = metrics.get("local_loss")
-        hist.meta["local_loss"].append(
-            None if lloss is None else float(lloss))
-        if "corr_loss" in metrics:
-            hist.meta["corr_loss"].append(float(metrics["corr_loss"]))
-            hist.meta["corr_rounds"].append(r)
-        bytes_cum += bpr(r, k)
-        steps_cum += spr(r, k)
-        loss, score = evaluate(state.params)
-        hist.meta["round_seconds"].append(time.perf_counter() - t0)
-        hist.rounds.append(r)
-        hist.steps_cum.append(steps_cum)
-        hist.val_score.append(score)
-        hist.train_loss.append(loss)
-        hist.bytes_cum.append(bytes_cum)
-        if checkpoint_dir:
-            from repro.checkpoint.store import save_checkpoint
-            save_checkpoint(checkpoint_dir, r, state.params,
-                            extra={"strategy": name, "round": r,
-                                   "val_score": score},
-                            keep=checkpoint_keep)
-        if checkpoint_hook is not None:
-            checkpoint_hook.commit(r, state, hist)
+        with jax.profiler.StepTraceAnnotation("llcg.round", step_num=r):
+            t0 = time.perf_counter()
+            inputs = pending if prefetch else draw(r, k)
+            with span("dispatch"):
+                state, metrics = program.run_round(state, feats, labels,
+                                                   inputs)
+            if checkpoint_hook is not None:
+                # BEFORE the prefetch draw: the snapshot must capture the
+                # RNG streams at "rounds 1..r drawn, nothing beyond"
+                with span("checkpoint"):
+                    checkpoint_hook.after_round(r, state)
+            if prefetch:
+                # the overlap: round r's scan is in flight, nothing has
+                # blocked on it yet — issue round r+1's sample NOW
+                pending = (draw(r + 1, schedule[r]) if r < len(schedule)
+                           else None)
+            lloss = metrics.get("local_loss")
+            hist.meta["local_loss"].append(
+                None if lloss is None else read(lloss))
+            if "corr_loss" in metrics:
+                hist.meta["corr_loss"].append(read(metrics["corr_loss"]))
+                hist.meta["corr_rounds"].append(r)
+            bytes_cum += bpr(r, k)
+            steps_cum += spr(r, k)
+            with span("evaluate"):
+                loss, score = evaluate(state.params)
+            hist.meta["round_seconds"].append(time.perf_counter() - t0)
+            hist.rounds.append(r)
+            hist.steps_cum.append(steps_cum)
+            hist.val_score.append(score)
+            hist.train_loss.append(loss)
+            hist.bytes_cum.append(bytes_cum)
+            if checkpoint_dir:
+                from repro.checkpoint.store import save_checkpoint
+                with span("checkpoint"):
+                    save_checkpoint(checkpoint_dir, r, state.params,
+                                    extra={"strategy": name, "round": r,
+                                           "val_score": score},
+                                    keep=checkpoint_keep)
+            if checkpoint_hook is not None:
+                with span("checkpoint"):
+                    checkpoint_hook.commit(r, state, hist)
     hist.meta["final_params"] = state.params
     hist.meta["num_retraces"] = program.num_retraces
     hist.meta["num_corr_retraces"] = getattr(program, "num_corr_retraces", 0)

@@ -76,7 +76,7 @@ from repro.checkpoint.manager import (
 from repro.comm.compress import averaging_payload_bytes
 from repro.core.engine import (
     EngineConfig, EngineState, History, ResumePoint, RoundInputs,
-    RoundProgram, run_schedule,
+    RoundProgram, run_schedule, span,
 )
 from repro.core.machine import make_eval_fn, make_machine_step
 from repro.core.schedules import KBucketing, local_epoch_schedule
@@ -993,7 +993,10 @@ class RoundSampler:
             bmasks = _f32_mask((1, desc.k, B))
         else:
             raise ValueError(f"unknown round kind {desc.kind!r}")
-        corr = self.sample_correction() if desc.correction else {}
+        corr = {}
+        if desc.correction:
+            with span("correction_draw"):
+                corr = self.sample_correction()
         halo = {}
         if desc.kind == "ext" and desc.mode == "halo":
             halo = self.halo_inputs
@@ -1021,7 +1024,8 @@ class RoundSampler:
         loss, score = self.eval_fn(params, self.full_feats, self.full_table_j,
                                    self.full_mask_j, self.full_labels,
                                    jnp.asarray(nodes))
-        return float(loss), float(score)
+        with span("read"):
+            return float(loss), float(score)
 
     def cut_stats(self) -> Dict:
         from repro.graph.partition import cut_edge_stats

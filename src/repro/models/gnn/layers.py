@@ -31,29 +31,31 @@ from repro.models.gnn.agg import (
 def mean_aggregate(h: jnp.ndarray, table: jnp.ndarray, mask: jnp.ndarray,
                    agg: Optional[AggOperands] = None) -> jnp.ndarray:
     """(1/|Ñ(v)|) Σ_{j∈Ñ(v)} h_j — the paper's mean aggregation."""
-    if agg is not None:
-        if agg.layout == "csr":
-            return csr_mean_aggregate(h, agg.edges)
-        if agg.layout == "bcsr_kernel":
-            return bcsr_mean_aggregate(h, agg.bcsr)
-    gathered = h[table]                           # (N, fanout, d)
-    s = jnp.einsum("nfd,nf->nd", gathered, mask)
-    denom = jnp.clip(mask.sum(-1, keepdims=True), 1.0, None)
-    return s / denom
+    with jax.named_scope("aggregate"):
+        if agg is not None:
+            if agg.layout == "csr":
+                return csr_mean_aggregate(h, agg.edges)
+            if agg.layout == "bcsr_kernel":
+                return bcsr_mean_aggregate(h, agg.bcsr)
+        gathered = h[table]                       # (N, fanout, d)
+        s = jnp.einsum("nfd,nf->nd", gathered, mask)
+        denom = jnp.clip(mask.sum(-1, keepdims=True), 1.0, None)
+        return s / denom
 
 
 def sym_aggregate(h: jnp.ndarray, table: jnp.ndarray, mask: jnp.ndarray,
                   normalizers: jnp.ndarray,
                   agg: Optional[AggOperands] = None) -> jnp.ndarray:
     """Σ_j h_j / sqrt(deg_i · deg_j) — GCN symmetric-Laplacian aggregation."""
-    if agg is not None:
-        if agg.layout == "csr":
-            return csr_sym_aggregate(h, agg.edges, normalizers)
-        if agg.layout == "bcsr_kernel":
-            return bcsr_sym_aggregate(h, agg.bcsr, normalizers)
-    gathered = h[table]                           # (N, fanout, d)
-    coef = mask * normalizers[table] * normalizers[:, None]
-    return jnp.einsum("nfd,nf->nd", gathered, coef)
+    with jax.named_scope("aggregate"):
+        if agg is not None:
+            if agg.layout == "csr":
+                return csr_sym_aggregate(h, agg.edges, normalizers)
+            if agg.layout == "bcsr_kernel":
+                return bcsr_sym_aggregate(h, agg.bcsr, normalizers)
+        gathered = h[table]                       # (N, fanout, d)
+        coef = mask * normalizers[table] * normalizers[:, None]
+        return jnp.einsum("nfd,nf->nd", gathered, coef)
 
 
 def gcn_layer(params: Dict, h: jnp.ndarray, table: jnp.ndarray,
