@@ -152,6 +152,9 @@ def train(data, model, plan, meter: CompileMeter, tag: str, **trainer_kw):
     # params first arrive replicated); the round walls show where
     print(f"[{tag}] compile_s={c1 - c0:.1f} cache_hits={h1 - h0} "
           f"cache_misses={m1 - m0} retraces={hist.meta['num_retraces']} "
+          f"full_agg_slots={hist.meta['full_agg_slots']} "
+          f"full_agg_edges={hist.meta['full_agg_edges']} "
+          f"full_agg_buckets={hist.meta['full_agg_buckets']} "
           f"run_s={wall:.1f}")
     losses = np.asarray(local + corr, np.float64)
     check(bool(np.all(np.isfinite(losses))), f"{tag}: non-finite loss")

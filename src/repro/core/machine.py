@@ -152,11 +152,14 @@ def make_machine_step(model: GNNModel, optimizer: Optimizer) -> MachineStep:
 
 def make_eval_fn(model: GNNModel) -> Callable:
     """Full-graph, full-neighbor evaluation (the paper's 'global validation
-    score' — computed on the server with the complete graph)."""
+    score' — computed on the server with the complete graph).  ``agg``
+    optionally carries prebuilt aggregation operands for the full-neighbor
+    table (the degree buckets of :func:`repro.models.gnn.agg.
+    bucketed_operands`); ``None`` aggregates over ``table``/``mask``."""
 
     @jax.jit
-    def evaluate(params, feats, table, mask, labels, nodes):
-        logits = model.apply(params, feats, table, mask)
+    def evaluate(params, feats, table, mask, labels, nodes, agg=None):
+        logits = model.apply(params, feats, table, mask, agg=agg)
         loss = cross_entropy_on_batch(logits, labels, nodes)
         score = f1_micro(logits, labels, nodes)
         return loss, score

@@ -91,7 +91,8 @@ from repro.graph.sampling import (
     sample_round_device,
 )
 from repro.models.gnn.agg import (
-    LAYOUTS as AGG_LAYOUTS, build_agg_operands, choose_layout,
+    LAYOUTS as AGG_LAYOUTS, build_agg_operands, bucketed_operands,
+    choose_layout, full_table_stats,
 )
 from repro.models.gnn.model import GNNModel
 from repro.optim import OPTIMIZERS, Optimizer, make_optimizer
@@ -624,22 +625,32 @@ class RoundSampler:
         self.server_opt = make_optimizer(loc.optimizer, server_lr)
         self.eval_fn = make_eval_fn(model)
 
-        # full-graph full-neighbor table for eval + correction
-        self.full_table, self.full_mask = build_neighbor_table(data.graph)
+        # full-graph full-neighbor operands for eval + correction: the
+        # degree buckets; the single (N, max_deg) table only where a layer
+        # reads it besides (GAT), else a zero-width stand-in
         self.full_feats = jnp.asarray(data.features)
         self.full_labels = jnp.asarray(data.labels)
-        self.full_table_j = jnp.asarray(self.full_table)
-        self.full_mask_j = jnp.asarray(self.full_mask)
+        if model.reads_full_table:
+            full_table, full_mask = build_neighbor_table(data.graph)
+        else:
+            full_table = np.zeros((data.num_nodes, 0), np.int32)
+            full_mask = np.zeros((data.num_nodes, 0), np.float32)
+        self.full_table_j = jnp.asarray(full_table)
+        self.full_mask_j = jnp.asarray(full_mask)
+        self.full_agg = bucketed_operands(data.graph)
+        self.full_agg_stats = full_table_stats(data.graph)
 
         # correction-phase aggregation layout, resolved ONCE against the
         # full table's geometry (the correction regime IS the full-neighbor
-        # regime the cost model targets); operands build lazily/at prewarm
+        # regime the cost model targets; padded gathers the buckets'
+        # slots); operands build lazily/at prewarm
+        full_width = max(data.graph.max_degree(), 1)
         self.corr_agg_layout = choose_layout(
             srv.agg_layout, num_nodes=data.num_nodes,
             num_edges=data.graph.num_edges,
-            width=self.full_table.shape[1],
-            full_width=self.full_table.shape[1],
-            sampled=srv.correction_sampling)
+            width=full_width, full_width=full_width,
+            sampled=srv.correction_sampling,
+            padded_slots=self.full_agg_stats["full_agg_slots"])
         self._corr_agg = None
 
         params0 = model.init(plan.seed)
@@ -851,9 +862,13 @@ class RoundSampler:
     # --------------------------------------------------------------- server
     def correction_operands(self):
         """The correction forward's prebuilt :class:`~repro.models.gnn.agg.
-        AggOperands` (None for the padded layout), cached on the graph."""
+        AggOperands`, cached on the graph: the degree buckets for the padded
+        layout on the full table (None for the per-step sampled tables of
+        sampling-at-correction)."""
         if self.corr_agg_layout == "padded":
-            return None
+            if self.plan.server.correction_sampling:
+                return None
+            return self.full_agg
         if self._corr_agg is None:
             self._corr_agg = build_agg_operands(self.data.graph,
                                                 self.corr_agg_layout)
@@ -1023,7 +1038,7 @@ class RoundSampler:
     def evaluate(self, params, nodes):
         loss, score = self.eval_fn(params, self.full_feats, self.full_table_j,
                                    self.full_mask_j, self.full_labels,
-                                   jnp.asarray(nodes))
+                                   jnp.asarray(nodes), self.full_agg)
         with span("read"):
             return float(loss), float(score)
 
@@ -1343,7 +1358,8 @@ class PlanTrainer:
                       "plan": plan.describe(),
                       "sampler_placement": sampler.placement,
                       "sampler_overlap": plan.sampler.resolved_overlap,
-                      "corr_agg_layout": sampler.corr_agg_layout}
+                      "corr_agg_layout": sampler.corr_agg_layout,
+                      **sampler.full_agg_stats}
         if any(d.kind == "ext" for d in self.descs):
             meta.update({
                 "halo_executed": not plan.comm.host_halo,

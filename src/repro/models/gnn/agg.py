@@ -34,6 +34,16 @@ baked-in lowering:
     tables always resolve to padded — the edge-centric operands encode the
     FULL edge set, which is different math from a subsampled table.
 
+Degree buckets (:func:`degree_buckets`, layout ``"bucketed"``)
+    The padded path itself, split by degree: nodes grouped by their degree
+    rounded up (multiples of 8 to 32, then powers of two), one
+    ``(N_b, w_b)`` table per bucket, and one permutation back to node
+    order.  Each row sums the same neighbors in the same slot order as the
+    single ``max_deg``-wide table; only all-padding slots are dropped.  Not
+    a user option: the full-neighbor mean/sym consumers always take it.  A
+    near-regular graph comes out as one bucket in node order, which is the
+    single table and its program.
+
 Operands are prebuilt host-side once per graph and cached on the graph
 object (the ``_all_nodes_plan`` / ``RoundSampler.prewarm`` idiom), so no
 layout pays a rebuild inside the round.
@@ -42,13 +52,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional, Sequence
+from typing import Any, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.graph.csr import CSRGraph
+from repro.graph.csr import CSRGraph, gather_neighbor_rows
 
 #: Selectable aggregation layouts.
 LAYOUTS = ("padded", "csr", "bcsr_kernel", "auto")
@@ -57,7 +67,6 @@ LAYOUTS = ("padded", "csr", "bcsr_kernel", "auto")
 #: work.  2.0 keeps padded for near-dense tables where the gather's locality
 #: beats the scatter.
 AUTO_THRESHOLD = 2.0
-
 
 # --------------------------------------------------------------------------
 # Operand containers (pytrees: jit/vmap/scan-safe, layout string is static)
@@ -121,22 +130,54 @@ jax.tree_util.register_pytree_node(BCSROps, _bcsr_flatten, _bcsr_unflatten)
 
 
 @dataclasses.dataclass(frozen=True)
+class DegreeBuckets:
+    """The full-neighbor table split into degree buckets.
+
+    Bucket ``b`` holds the rows ``order[o_b : o_b + N_b]`` (``o_b`` the
+    sum of the earlier buckets' row counts); its ``(N_b, w_b)`` table keeps
+    each row's slots in the single table's order, and pad slots point at
+    the row's own node with mask 0.  ``slot_of[v]`` is node ``v``'s row in
+    the concatenated bucket outputs, so ``concat[slot_of]`` is node order
+    (and ``order`` the inverse permutation).  A width-0 bucket holds the
+    zero-degree nodes.
+    """
+
+    tables: Tuple[Any, ...]   # per bucket (N_b, w_b) int32
+    masks: Tuple[Any, ...]    # per bucket (N_b, w_b) f32
+    order: Any                # (N,) int32 — node at each concatenated row
+    slot_of: Any              # (N,) int32 — concatenated row of each node
+
+
+def _buckets_flatten(b):
+    return (b.tables, b.masks, b.order, b.slot_of), None
+
+
+def _buckets_unflatten(aux, children):
+    return DegreeBuckets(*children)
+
+
+jax.tree_util.register_pytree_node(DegreeBuckets, _buckets_flatten,
+                                   _buckets_unflatten)
+
+
+@dataclasses.dataclass(frozen=True)
 class AggOperands:
     """The resolved layout + its prebuilt operands, threaded through
     ``GNNModel.apply`` down to the aggregate ops.  ``None`` anywhere in the
     stack means the padded path (bit-identical to pre-layout code)."""
 
-    layout: str               # "csr" | "bcsr_kernel" (static)
+    layout: str               # "csr" | "bcsr_kernel" | "bucketed" (static)
     edges: Optional[EdgeCSR] = None
     bcsr: Optional[BCSROps] = None
+    buckets: Optional[DegreeBuckets] = None
 
 
 def _agg_flatten(a):
-    return (a.edges, a.bcsr), a.layout
+    return (a.edges, a.bcsr, a.buckets), a.layout
 
 
 def _agg_unflatten(aux, children):
-    return AggOperands(layout=aux, edges=children[0], bcsr=children[1])
+    return AggOperands(aux, *children)
 
 
 jax.tree_util.register_pytree_node(AggOperands, _agg_flatten, _agg_unflatten)
@@ -234,15 +275,71 @@ def build_agg_operands(graph: CSRGraph, layout: str,
                      f"choose one of {LAYOUTS}")
 
 
+def bucket_widths(deg: np.ndarray, max_deg: int) -> np.ndarray:
+    """Each node's bucket width: its degree rounded up to a multiple of 8
+    up to 32, then up to a power of two (a logarithmic number of buckets on
+    a heavy-tailed graph), never past ``max_deg``; 0 for zero-degree
+    nodes."""
+    deg = np.asarray(deg, np.int64)
+    pow2 = 2 ** np.ceil(np.log2(np.maximum(deg, 1))).astype(np.int64)
+    return np.minimum(np.where(deg <= 32, -(-deg // 8) * 8, pow2), max_deg)
+
+
+def degree_buckets(graph: CSRGraph) -> DegreeBuckets:
+    """The graph's full-neighbor table as :class:`DegreeBuckets`, built once
+    and cached on the graph."""
+    cache = _graph_cache(graph)
+    if "buckets" not in cache:
+        deg, n = graph.degrees(), graph.num_nodes
+        width = bucket_widths(deg, max(graph.max_degree(), 1))
+        order = np.argsort(width, kind="stable")
+        widths, starts = np.unique(width[order], return_index=True)
+        tables, masks = [], []
+        for w, rows in zip(widths, np.split(order, starts[1:])):
+            if w == 0:
+                tab = np.zeros((rows.size, 0), np.int32)
+                msk = np.zeros((rows.size, 0), np.float32)
+            else:
+                tab, msk = gather_neighbor_rows(graph, rows, int(w))
+                tab = np.where(msk > 0, tab, rows[:, None]).astype(np.int32)
+            tables.append(jnp.asarray(tab))
+            masks.append(jnp.asarray(msk))
+        slot_of = np.empty(n, np.int32)
+        slot_of[order] = np.arange(n, dtype=np.int32)
+        cache["buckets"] = DegreeBuckets(
+            tables=tuple(tables), masks=tuple(masks),
+            order=jnp.asarray(order, jnp.int32),
+            slot_of=jnp.asarray(slot_of))
+    return cache["buckets"]
+
+
+def bucketed_operands(graph: CSRGraph) -> AggOperands:
+    """:func:`degree_buckets` as :class:`AggOperands` for the full-neighbor
+    aggregations."""
+    return AggOperands("bucketed", buckets=degree_buckets(graph))
+
+
+def full_table_stats(graph: CSRGraph) -> dict:
+    """Slots gathered per full-neighbor aggregation, the real edges among
+    them, and the number of tables: the engagement counters of
+    :func:`degree_buckets`."""
+    buckets = degree_buckets(graph)
+    return {"full_agg_slots": int(sum(t.size for t in buckets.tables)),
+            "full_agg_edges": graph.num_edges,
+            "full_agg_buckets": len(buckets.tables)}
+
+
 def choose_layout(layout: str, *, num_nodes: int, num_edges: int,
                   width: int, full_width: int, sampled: bool = False,
-                  threshold: float = AUTO_THRESHOLD) -> str:
+                  threshold: float = AUTO_THRESHOLD,
+                  padded_slots: Optional[int] = None) -> str:
     """Resolve ``"auto"`` via the padding-fraction cost model.
 
-    Padded-table work scales with ``num_nodes·width``; edge-centric work
-    with ``num_edges``.  Sampled or narrowed tables (``width <
-    full_width``) are different math from the full edge set and always
-    resolve to padded.  ``auto`` never picks ``bcsr_kernel`` — on this
+    Padded-table work scales with the slots it gathers, ``padded_slots``
+    (``num_nodes·width`` for one table; fewer where the full table is split
+    into degree buckets); edge-centric work with ``num_edges``.  Sampled or
+    narrowed tables (``width < full_width``) are different math from the
+    full edge set and always resolve to padded.  ``auto`` never picks ``bcsr_kernel`` — on this
     container the Pallas kernels run in interpret mode, so the kernel
     layout is an explicit opt-in for real hardware.
     """
@@ -253,7 +350,8 @@ def choose_layout(layout: str, *, num_nodes: int, num_edges: int,
         return layout
     if sampled or width < full_width:
         return "padded"
-    padded_work = num_nodes * max(int(width), 1)
+    padded_work = (num_nodes * max(int(width), 1) if padded_slots is None
+                   else int(padded_slots))
     if padded_work >= threshold * max(int(num_edges), 1):
         return "csr"
     return "padded"
@@ -347,6 +445,43 @@ def csr_gat_aggregate(z: jnp.ndarray, src_score: jnp.ndarray,
     den = jax.ops.segment_sum(num, seg, num_segments=ns)
     out = jax.ops.segment_sum(num[:, None] * z[nbr], seg, num_segments=ns)
     return out / jnp.maximum(den, 1e-30)[:, None]
+
+
+# --------------------------------------------------------------------------
+# Degree-bucket primitives (bucketed layout)
+# --------------------------------------------------------------------------
+def _bucketed(h: jnp.ndarray, buckets: DegreeBuckets, reduce) -> jnp.ndarray:
+    """``reduce(h[tab_b], tab_b, mask_b, rows_b)`` per bucket, back in node
+    order.  ``rows_b`` are a bucket's node ids; a width-0 bucket's rows sum
+    nothing and come out zero, as an all-padding row of the single table
+    does."""
+    parts, start = [], 0
+    for tab, mask in zip(buckets.tables, buckets.masks):
+        rows = buckets.order[start:start + tab.shape[0]]
+        parts.append(reduce(h[tab], tab, mask, rows))
+        start += tab.shape[0]
+    if len(parts) == 1:              # one bucket holds every node in order
+        return parts[0]
+    return jnp.concatenate(parts)[buckets.slot_of]
+
+
+def bucketed_mean_aggregate(h: jnp.ndarray,
+                            buckets: DegreeBuckets) -> jnp.ndarray:
+    """The padded mean over each bucket's ``w_b`` slots: masked sum over
+    the mask sum, as the single table computes it."""
+    def reduce(gathered, tab, mask, rows):
+        s = jnp.einsum("nfd,nf->nd", gathered, mask)
+        return s / jnp.clip(mask.sum(-1, keepdims=True), 1.0, None)
+    return _bucketed(h, buckets, reduce)
+
+
+def bucketed_sym_aggregate(h: jnp.ndarray, buckets: DegreeBuckets,
+                           normalizers: jnp.ndarray) -> jnp.ndarray:
+    """The padded ``Σ_j h_j · nrm_i · nrm_j`` over each bucket's slots."""
+    def reduce(gathered, tab, mask, rows):
+        coef = mask * normalizers[tab] * normalizers[rows][:, None]
+        return jnp.einsum("nfd,nf->nd", gathered, coef)
+    return _bucketed(h, buckets, reduce)
 
 
 # --------------------------------------------------------------------------

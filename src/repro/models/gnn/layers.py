@@ -12,8 +12,9 @@ accepts prebuilt :class:`repro.models.gnn.agg.AggOperands` (``agg=``): the
 ``csr`` layout replaces the ``N·fanout·d`` dense gather with an ``E·d``
 edge-centric segment-sum, ``bcsr_kernel`` routes through the Pallas
 BCSR SpMM / fused edge-softmax kernels — the full-neighbor paths of the
-server-correction step and exact serving.  ``agg=None`` (the default) is
-the unchanged padded path.
+server-correction step and exact serving — and ``bucketed`` splits the
+full-neighbor table by degree (mean/sym only; GAT keeps ``table``/``mask``).
+``agg=None`` (the default) is the unchanged padded path.
 """
 from __future__ import annotations
 
@@ -23,7 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.gnn.agg import (
-    AggOperands, bcsr_mean_aggregate, bcsr_sym_aggregate, csr_gat_aggregate,
+    AggOperands, bcsr_mean_aggregate, bcsr_sym_aggregate,
+    bucketed_mean_aggregate, bucketed_sym_aggregate, csr_gat_aggregate,
     csr_mean_aggregate, csr_sym_aggregate,
 )
 
@@ -37,6 +39,8 @@ def mean_aggregate(h: jnp.ndarray, table: jnp.ndarray, mask: jnp.ndarray,
                 return csr_mean_aggregate(h, agg.edges)
             if agg.layout == "bcsr_kernel":
                 return bcsr_mean_aggregate(h, agg.bcsr)
+            if agg.layout == "bucketed":
+                return bucketed_mean_aggregate(h, agg.buckets)
         gathered = h[table]                       # (N, fanout, d)
         s = jnp.einsum("nfd,nf->nd", gathered, mask)
         denom = jnp.clip(mask.sum(-1, keepdims=True), 1.0, None)
@@ -53,6 +57,8 @@ def sym_aggregate(h: jnp.ndarray, table: jnp.ndarray, mask: jnp.ndarray,
                 return csr_sym_aggregate(h, agg.edges, normalizers)
             if agg.layout == "bcsr_kernel":
                 return bcsr_sym_aggregate(h, agg.bcsr, normalizers)
+            if agg.layout == "bucketed":
+                return bucketed_sym_aggregate(h, agg.buckets, normalizers)
         gathered = h[table]                       # (N, fanout, d)
         coef = mask * normalizers[table] * normalizers[:, None]
         return jnp.einsum("nfd,nf->nd", gathered, coef)

@@ -104,6 +104,14 @@ class GNNModel:
             return self.appnp_steps
         return sum(1 for op in self.arch if op in ("G", "S"))
 
+    @property
+    def reads_full_table(self) -> bool:
+        """Whether a full-neighbor forward reads the padded ``table`` and
+        ``mask`` whatever ``agg`` it is given: GAT's attention scores each
+        slot and ignores the degree buckets; the mean aggregations of the
+        other layers take the buckets instead."""
+        return self.arch == "GAT"
+
     def _dims(self) -> List[Tuple[int, int]]:
         """(d_in, d_out) per op; BatchNorm keeps width."""
         dims = []

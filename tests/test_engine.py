@@ -25,6 +25,7 @@ from repro.core.machine import make_machine_step
 from repro.core.strategies import _Context
 from repro.data.graph_loader import sample_round
 from repro.graph import sbm_graph
+from repro.graph.csr import build_neighbor_table
 from repro.models.gnn import build_model
 from repro.utils.pytree import tree_average
 
@@ -59,7 +60,9 @@ def test_vmap_round_matches_sequential_steps(tiny):
     state = program.init_state(params0)
     state, _ = program.run_round(state, ctx.feats_j, ctx.labels_j, inputs)
 
-    # sequential reference: the pre-engine per-step loop
+    # sequential reference: the pre-engine per-step loop, its correction on
+    # the single full-neighbor table (the engine runs the degree buckets)
+    table, mask = map(jnp.asarray, build_neighbor_table(data.graph))
     sstep = make_machine_step(model, ctx.server_opt)
     P = cfg.num_machines
     local = []
@@ -75,8 +78,8 @@ def test_vmap_round_matches_sequential_steps(tiny):
     so = ctx.server_opt.init(params0)
     for s in range(cfg.correction_steps):
         ref, so, _ = sstep.local_step(
-            ref, so, inputs.corr_feats, inputs.corr_tables,
-            inputs.corr_masks, inputs.corr_batches[s], inputs.corr_labels,
+            ref, so, inputs.corr_feats, table, mask,
+            inputs.corr_batches[s], inputs.corr_labels,
             inputs.corr_bmasks[s])
 
     for got, want in zip(jax.tree_util.tree_leaves(state.params),
@@ -93,6 +96,7 @@ def test_run_llcg_trajectory_matches_sequential_reference(tiny):
     # reference run re-creates the context (identical seeds → identical
     # sampler/batch RNG streams) and loops machines/steps in Python
     ctx = _Context(data, model, cfg)
+    table, mask = map(jnp.asarray, build_neighbor_table(data.graph))
     sstep = make_machine_step(model, ctx.server_opt)
     params = model.init(cfg.seed)
     server_state = ctx.server_opt.init(params)
@@ -115,8 +119,7 @@ def test_run_llcg_trajectory_matches_sequential_reference(tiny):
         params = tree_average(local)
         for s in range(cfg.correction_steps):
             params, server_state, _ = sstep.local_step(
-                params, server_state, corr["corr_feats"],
-                corr["corr_tables"], corr["corr_masks"],
+                params, server_state, corr["corr_feats"], table, mask,
                 corr["corr_batches"][s], corr["corr_labels"],
                 corr["corr_bmasks"][s])
         loss, score = ctx.evaluate(params, data.val_nodes)

@@ -13,11 +13,14 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.engine import EngineConfig, RoundProgram
+from repro.core.machine import make_loss_fn
+from repro.graph.datasets import rmat_graph
 from repro.kernels.edge_softmax import edge_softmax
 from repro.kernels.linear_scan import linear_scan_chunked
 from repro.kernels.quantize import dequantize_rows, quantize_rows
 from repro.kernels.spmm import spmm_bcsr
 from repro.models.gnn import build_model
+from repro.models.gnn.agg import bucketed_operands
 from repro.optim import adam
 
 # ogbn-arxiv widths (see chip_smoke.py): 128-d features, 40 classes, GBGBG
@@ -113,4 +116,23 @@ def test_llcg_round_compiles_for_v5e_at_arxiv_width(one_chip):
             _sds(s, (P, K, BATCH), jnp.int32), _sds(s, (P, K, BATCH)),
             _sds(s, (K,)))
     compiled = program._round.lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+def test_bucketed_correction_step_compiles_for_v5e(one_chip):
+    """The correction's gradient over degree-bucketed full-neighbor tables,
+    zero-degree (width-0) bucket included, at arxiv width, with the
+    zero-width stand-in for the single table that the plan passes."""
+    graph = rmat_graph(num_nodes=NODES, num_edges=4 * NODES, seed=0).graph
+    agg = bucketed_operands(graph)
+    assert agg.buckets.tables[0].shape[1] == 0
+    model = build_model("GBGBG", FEAT, CLASSES, hidden_dim=HIDDEN)
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: _sds(one_chip, x.shape, x.dtype), tree)
+    s = one_chip
+    args = (place(jax.eval_shape(lambda: model.init(0))),
+            _sds(s, (NODES, FEAT)), _sds(s, (NODES, 0), jnp.int32),
+            _sds(s, (NODES, 0)), _sds(s, (BATCH,), jnp.int32),
+            _sds(s, (NODES,), jnp.int32), _sds(s, (BATCH,)), place(agg))
+    compiled = jax.jit(jax.grad(make_loss_fn(model))).lower(*args).compile()
     assert compiled.memory_analysis().temp_size_in_bytes > 0
