@@ -11,10 +11,14 @@ utilisation past the peak.
 * the correction and the evaluation count once per round, even where every
   chip repeats them;
 * BatchNorm, activations and the loss are not counted.
+
+It is the counts module of the configurations whose operators are the
+paper's ``G``/``S``/``L``/``B`` (``"counts"`` in the configuration file):
+the harness calls :func:`flops_per_round` and :func:`kernel_work`.
 """
 from __future__ import annotations
 
-from typing import Sequence
+from typing import Dict, Sequence
 
 
 def forward_flops(arch: str, d_in: int, hidden: int, classes: int,
@@ -49,3 +53,20 @@ def round_flops(arch: str, d_in: int, hidden: int, classes: int,
                                           strict=True))
     full = fwd(num_nodes, directed_edges)
     return 3 * local_k * local + 3 * correction_steps * full + full
+
+
+def flops_per_round(config: Dict, traffic: Dict, ref: Dict) -> int:
+    """:func:`round_flops` of the configuration's model on its graph, with
+    the partition's rows and sampled edges as the reference counted them."""
+    model, ds = config["model"], config["dataset"]
+    return round_flops(model["arch"], ds["feature_dim"], model["hidden_dim"],
+                       ds["num_classes"], ref["part_rows"],
+                       ref["part_sampled_edges"], ds["num_nodes"],
+                       ref["directed_edges"], traffic["local_k"],
+                       traffic["correction_steps"])
+
+
+def kernel_work(config: Dict, traffic: Dict, ref: Dict) -> Dict[str, Dict]:
+    """No kernel of these operators has a roofline share of its own: their
+    aggregations are XLA gathers, not kernels."""
+    return {}

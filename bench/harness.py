@@ -1,7 +1,8 @@
 """One run of one benchmark cell, driven by the files that name it.
 
 A cell of ``BENCHMARK.json`` names a configuration (``configs[].file``: the
-dataset, the model, the precision and the plain reference), a traffic mix
+dataset, the model, the precision, the plain reference and the module that
+counts the round's work), a traffic mix
 (``bench/traffic/<traffic>.json``: the training plan, its backend and its
 warm-up) and has a file of its own (``bench/workloads/<cell>.json``: the
 limits of the numbers that decide ``correct``).  Per-layer metrics are
@@ -38,7 +39,6 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 import checks
-import flops
 import tracereduce
 from sbm import sbm_graph
 
@@ -453,12 +453,20 @@ def _start_trace(log_dir: str) -> None:
 # --------------------------------------------------------------- result
 @dataclasses.dataclass
 class Measured:
-    """What a per-layer metric reader is given."""
+    """What a per-layer metric reader is given: the traced window, the
+    round's model FLOPs and per-kernel work from the configuration's counts
+    module (``work``: ``{label: {"pattern", "flops", "bytes"}}``, per round
+    and chip), the chips, and each chip's peaks; ``config`` and ``traffic``
+    are the cell's files."""
 
     window: Optional[tracereduce.Window]
     flops_per_round: Optional[float]
     chips: int
     peak_flops: Optional[float]
+    config: Dict = dataclasses.field(default_factory=dict)
+    traffic: Dict = dataclasses.field(default_factory=dict)
+    work: Dict[str, Dict] = dataclasses.field(default_factory=dict)
+    peak_hbm_bytes_per_s: Optional[float] = None
 
     @staticmethod
     def first_device(w: tracereduce.Window) -> int:
@@ -474,13 +482,27 @@ def load_reader(metric: str):
     return mod.read
 
 
-def load_reference(config: Dict):
-    path = os.path.join(ROOT, config["reference"])
+def _load_named(config: Dict, key: str):
+    """The module at the path the configuration gives under ``key``."""
+    if key not in config:
+        raise BenchError(f"configuration {config.get('name')!r} names no "
+                         f"{key!r} module")
+    path = os.path.join(ROOT, config[key])
     spec = importlib.util.spec_from_file_location(
-        "bench_reference_" + os.path.basename(path)[:-3], path)
+        f"bench_{key}_" + os.path.basename(path)[:-3], path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_reference(config: Dict):
+    return _load_named(config, "reference")
+
+
+def load_counts(config: Dict):
+    """The configuration's counts module: ``flops_per_round(config,
+    traffic, ref)`` and ``kernel_work(config, traffic, ref)``."""
+    return _load_named(config, "counts")
 
 
 def peak_of(kind: str) -> Dict:
@@ -522,6 +544,21 @@ class Trained:
     slices_off_chip: Optional[int]
 
 
+def model_options(model: Dict) -> Dict:
+    """The configuration's model block, but ``arch``, as ``build_model``'s
+    keywords; a key that ``GNNModel`` does not take is refused by name.
+    The data gives the feature and class counts."""
+    from repro.models.gnn.model import GNNModel
+    taken = ({f.name for f in dataclasses.fields(GNNModel)}
+             - {"arch", "feature_dim", "num_classes"})
+    options = {k: v for k, v in model.items() if k != "arch"}
+    unknown = sorted(set(options) - taken)
+    if unknown:
+        raise BenchError(f"model key(s) {unknown} are not GNNModel's; it "
+                         f"takes {sorted(taken)}")
+    return options
+
+
 def run_program(cell: Cell, used: List, seed: int, seconds: float,
                 t_process: float, meter: CompileMeter,
                 trace_dir: Optional[str] = None,
@@ -531,12 +568,12 @@ def run_program(cell: Cell, used: List, seed: int, seconds: float,
     from repro.models.gnn import build_model
 
     tr, cfg = cell.traffic, cell.config
+    options = model_options(cfg["model"])
     t_data = time.perf_counter()
     arrays = dataset_arrays(cfg, cache_dir)
     data = program_dataset(arrays, cfg)
     model = build_model(cfg["model"]["arch"], data.feature_dim,
-                        data.num_classes,
-                        hidden_dim=cfg["model"]["hidden_dim"])
+                        data.num_classes, **options)
     mesh = None
     if tr["backend"] == "shard_map":
         from jax.sharding import Mesh
@@ -619,6 +656,26 @@ def compared(trained: Trained, ref: Dict) -> Dict[str, float]:
     return values
 
 
+def count_work(cell: Cell, ref: Dict, chips: int,
+               peak: Optional[Dict]) -> Measured:
+    """The round's work as the configuration's counts module gives it, in
+    a :class:`Measured` that still lacks its window."""
+    counts = load_counts(cell.config)
+    args = (cell.config, cell.traffic, ref)
+    per_round = counts.flops_per_round(*args)
+    work = counts.kernel_work(*args)
+    for label, w in work.items():
+        if set(w) != {"pattern", "flops", "bytes"}:
+            raise BenchError(f"kernel_work[{label!r}] needs pattern, flops "
+                             f"and bytes; has {sorted(w)}")
+    log(f"[bench] flops_per_round={per_round}")
+    return Measured(None, float(per_round), chips,
+                    peak["bf16_flops_per_s"] if peak else None,
+                    config=cell.config, traffic=cell.traffic, work=work,
+                    peak_hbm_bytes_per_s=(peak["hbm_bytes_per_s"] if peak
+                                          else None))
+
+
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
              t_process: float, require_tpu: bool = True,
              cache_dir: str = CACHE_DIR) -> Dict:
@@ -638,15 +695,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                           trace_dir=trace_dir, cache_dir=cache_dir)
     ref = run_reference(cell, trained.arrays, seed)
     judged = checks.judge(compared(trained, ref), cell.limits)
-
-    cfg, tr = cell.config, cell.traffic
-    n, d = trained.arrays["features"].shape
-    per_round_flops = flops.round_flops(
-        cfg["model"]["arch"], d, cfg["model"]["hidden_dim"],
-        cfg["dataset"]["num_classes"], ref["part_rows"],
-        ref["part_sampled_edges"], n, ref["directed_edges"], tr["local_k"],
-        tr["correction_steps"])
-    log(f"[bench] flops_per_round={per_round_flops}")
+    counted = count_work(cell, ref, len(used), peak)
     import jax
     result: Dict[str, Any] = {
         "correct": checks.all_within(judged),
@@ -673,8 +722,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         log(f"[bench] trace_read_s={time.perf_counter() - t_read:.3f}")
         if window is not None and not (window.ops or window.modules):
             window = None       # no device in the trace (the CPU backend)
-        measured = Measured(window, float(per_round_flops), len(used),
-                            peak["bf16_flops_per_s"] if peak else None)
+        measured = dataclasses.replace(counted, window=window)
         for m in cell.per_layer:
             v = load_reader(m["name"])(measured)
             if v is not None:

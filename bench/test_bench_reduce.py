@@ -1,5 +1,5 @@
-"""The trace reduction and the FLOP count, on a synthesised trace and on
-hand counts for tiny models."""
+"""The trace reduction, the FLOP count and a kernel's roofline share, on a
+synthesised trace and on hand counts for tiny models."""
 import os
 import sys
 
@@ -9,6 +9,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import flops  # noqa: E402
 import harness  # noqa: E402
+import kernel_share  # noqa: E402
 import tracereduce as tr  # noqa: E402
 from tracereduce import Event, Line, Plane  # noqa: E402
 
@@ -145,3 +146,56 @@ def test_round_flops_counts_local_correction_and_eval():
                             correction_steps=1)
     assert got == (3 * 2 * (fwd(5, 4) + fwd(6, 3)) + 3 * fwd(11, 12)
                    + fwd(11, 12))
+
+
+def test_flops_per_round_is_round_flops_of_the_configuration():
+    """arxiv-gbgbg's own files, with the partition's counts as the
+    reference reports them for eight machines."""
+    cell = harness.load_cell("arxiv.llcg", harness.ROOT)
+    ref = {"part_rows": [21168] * 7 + [21167],
+           "part_sampled_edges": [180_001 + p for p in range(8)],
+           "directed_edges": 2_334_382}
+    got = flops.flops_per_round(cell.config, cell.traffic, ref)
+    assert got == flops.round_flops(
+        "GBGBG", 128, 256, 40, ref["part_rows"], ref["part_sampled_edges"],
+        169_343, 2_334_382, local_k=4, correction_steps=1)
+    assert flops.kernel_work(cell.config, cell.traffic, ref) == {}
+
+
+def test_a_configuration_without_counts_is_refused():
+    with pytest.raises(harness.BenchError, match="counts"):
+        harness.load_counts({"name": "bare", "reference": "x.py"})
+
+
+def _kernel(work, **kw):
+    """Device 0 of ``_trace`` runs ``fusion.1`` 300 ns over 2 rounds:
+    1.5e-7 s a round."""
+    return harness.Measured(tr.window_of(_trace()), flops_per_round=1e6,
+                            chips=1, peak_flops=1e15,
+                            work={"k": dict({"pattern": r"^fusion\.1$"},
+                                            **work)},
+                            peak_hbm_bytes_per_s=1e12, **kw)
+
+
+@pytest.mark.parametrize("work,expected", [
+    # compute-bound: 1.2e8 / 1e15 = 1.2e-7 s over 1e3 / 1e12 = 1e-9 s
+    ({"flops": 1.2e8, "bytes": 1e3}, 100 * 1.2e-7 / 1.5e-7),
+    # memory-bound: 6e4 / 1e12 = 6e-8 s over 1e6 / 1e15 = 1e-9 s
+    ({"flops": 1e6, "bytes": 6e4}, 100 * 6e-8 / 1.5e-7),
+])
+def test_kernel_share_by_hand(work, expected):
+    assert kernel_share.share(_kernel(work), "k") == pytest.approx(expected)
+
+
+def test_kernel_share_is_none_without_something_to_read():
+    work = {"flops": 1e6, "bytes": 6e4}
+    m = _kernel(work)
+    assert kernel_share.share(m, "other") is None               # no label
+    m.work["k"]["pattern"] = r"^edge_softmax"
+    assert kernel_share.share(m, "k") is None                   # no match
+    m = _kernel(work)
+    m.window = None
+    assert kernel_share.share(m, "k") is None                   # no trace
+    m = _kernel(work)
+    m.peak_hbm_bytes_per_s = None
+    assert kernel_share.share(m, "k") is None                   # no peak
