@@ -64,12 +64,13 @@ def bench_spmm() -> List[Dict]:
 
 def bench_edge_softmax() -> List[Dict]:
     rng = np.random.default_rng(0)
-    s = jnp.asarray(rng.standard_normal((512, 16)), jnp.float32)
+    s = jnp.asarray(rng.standard_normal((512, 16, 2)), jnp.float32)
     m = jnp.asarray((rng.random((512, 16)) > 0.3).astype(np.float32))
-    v = jnp.asarray(rng.standard_normal((512, 16, 64)), jnp.float32)
-    us_k = _time(edge_softmax_aggregate, s, m, v)
-    err = float(jnp.abs(edge_softmax_aggregate(s, m, v)
-                        - ref.edge_softmax_ref(s, m, v)).max())
+    z = jnp.asarray(rng.standard_normal((512, 64)), jnp.float32)
+    t = jnp.asarray(rng.integers(0, 512, (512, 16)), jnp.int32)
+    us_k = _time(edge_softmax_aggregate, s, m, z, t)
+    err = float(jnp.abs(edge_softmax_aggregate(s, m, z, t)
+                        - ref.edge_softmax_ref(s, m, z, t)).max())
     return [{"name": "kernel_edge_softmax", "us_per_call": us_k,
              "derived": f"max_err={err:.2e}"}]
 

@@ -3,13 +3,15 @@
 
     python chip_smoke.py            # one chip: device, train, kernels
     python chip_smoke.py --chips 4  # four chips: shard_map vs vmap only
+    python chip_smoke.py --phase gat_stack  # the GAT stack, kernel vs XLA
 
 Trains LLCG with ``build_trainer(data, model, plan).run()`` on a synthetic
 SBM graph at ogbn-arxiv's published size (Hu et al. 2020, "Open Graph
 Benchmark", ogbn-arxiv: 169,343 nodes, 1,166,243 edges, 128-d features, 40
 classes) with the repo's ``ogb-arxiv`` base arch ``GBGBG`` at hidden width
 256 (OGB's GCN baseline width for arxiv), then runs every Pallas kernel
-compiled against its oracle in :mod:`repro.kernels.ref`.  Weights and data
+compiled against its oracle in :mod:`repro.kernels.ref`, and the
+benchmark's GAT stack through the attention kernel against its XLA path.  Weights and data
 come from fixed seeds.
 
 Every phase prints what it found.  A failed check exits non-zero before the
@@ -54,6 +56,11 @@ from repro.models.gnn import build_model  # noqa: E402
 ARXIV = dict(num_nodes=169_343, num_classes=40, feature_dim=128,
              avg_degree=6.9, homophily=0.9, feature_snr=0.3)
 ARCH, HIDDEN = "GBGBG", 256
+# the benchmark's arxiv-gat stack (the OGB ogbn-arxiv GAT of Wang et al.
+# 2021): 3 layers of 3 heads × 250, residual projection, BatchNorm, the
+# node's own slot in its softmax
+GAT_STACK = dict(hidden_dim=250, num_heads=3, num_layers=3, residual=True,
+                 self_loop=True, batch_norm=True)
 # rwkv6-1.6b: 32 heads of 64
 SCAN_HEADS, SCAN_HEAD_DIM = 32, 64
 # Bound on |kernel - oracle| for a matmul on the MXU relative to the same
@@ -155,7 +162,10 @@ def train(data, model, plan, meter: CompileMeter, tag: str, **trainer_kw):
           f"full_agg_slots={hist.meta['full_agg_slots']} "
           f"full_agg_edges={hist.meta['full_agg_edges']} "
           f"full_agg_buckets={hist.meta['full_agg_buckets']} "
-          f"run_s={wall:.1f}")
+          + "".join(f"{k}={hist.meta[k]} " for k in (
+              "gat_local_slots", "gat_server_slots", "gat_chunk_bytes",
+              "gat_kernel") if k in hist.meta)
+          + f"run_s={wall:.1f}")
     losses = np.asarray(local + corr, np.float64)
     check(bool(np.all(np.isfinite(losses))), f"{tag}: non-finite loss")
     chance = 1.0 / data.num_classes
@@ -216,19 +226,21 @@ def kernel_phase(data, machines: int = 8, spmm_nodes: int = 8192,
              f"edges={sub.num_edges})", out, out_r, MXU_REL * mag,
              " bound=2^-6·|A||h|")
 
-    # fused GAT edge softmax: f32 on the VPU, so f32 rounding only — a few
-    # ulps per exp/divide/add over F terms; 1e-5 of Σ α|v| leaves margin and
-    # is far below what a bf16 pass would cost (4e-3)
-    scores = jax.random.normal(next(ks), (edge_rows, fanout))
+    # GAT edge attention, rows gathered in the kernel, 2 heads: f32 on the
+    # VPU, so f32 rounding only — a few ulps per exp/divide/add over F
+    # terms; 1e-5 of Σ α|z| leaves margin and is far below what a bf16 pass
+    # would cost (4e-3)
+    scores = jax.random.normal(next(ks), (edge_rows, fanout, 2))
     mask = (jax.random.uniform(next(ks), (edge_rows, fanout)) > 0.2
             ).astype(jnp.float32).at[:8].set(0.0)   # a few isolated rows
-    vals = jax.random.normal(next(ks), (edge_rows, fanout, HIDDEN))
-    out = edge_softmax_aggregate(scores, mask, vals)
+    z = jax.random.normal(next(ks), (edge_rows, HIDDEN))
+    table = jax.random.randint(next(ks), (edge_rows, fanout), 0, edge_rows)
+    out = edge_softmax_aggregate(scores, mask, z, table)
     with hi():
-        out_r = ref.edge_softmax_ref(scores, mask, vals)
-        mag = ref.edge_softmax_ref(scores, mask, jnp.abs(vals))
-    _compare(f"edge_softmax(n={edge_rows},F={fanout},d={HIDDEN})",
-             out, out_r, 1e-5 * mag, " bound=1e-5·Σα|v|")
+        out_r = ref.edge_softmax_ref(scores, mask, z, table)
+        mag = ref.edge_softmax_ref(scores, mask, jnp.abs(z), table)
+    _compare(f"edge_softmax(n={edge_rows},F={fanout},d={HIDDEN},heads=2)",
+             out, out_r, 1e-5 * mag, " bound=1e-5·Σα|z|")
 
     # gated linear scan at rwkv6-1.6b's head size.  log_w ∈ [-0.15, 0]:
     # the chunked form needs |Σ log_w| over a chunk well inside f32's exp
@@ -256,6 +268,71 @@ def kernel_phase(data, machines: int = 8, spmm_nodes: int = 8192,
              note)
     _compare(f"linear_scan_strict{shape}.h_T", h_s, h_sr, MXU_REL * h_m,
              note)
+
+
+def gat_stack_phase(data, batch: int = 64) -> None:
+    """The whole GAT stack at arxiv width over the full graph's degree
+    buckets, jitted through the attention kernel against the XLA path: the
+    forward alone (the evaluation's program) and the forward with the
+    gradient of a batch's loss (the correction's), bucket by bucket."""
+    from repro.models.gnn.agg import bucketed_operands
+    agg = bucketed_operands(data.graph)
+    n = data.graph.num_nodes
+    feats = jnp.asarray(data.features)
+    nodes = jnp.asarray(np.random.default_rng(0).choice(n, batch, False))
+    labels = jnp.asarray(data.labels)[nodes]
+    none_i = jnp.zeros((n, 0), jnp.int32)
+    none_f = jnp.zeros((n, 0), jnp.float32)
+    out = {}
+    for fused in (True, False):
+        model = build_model("GAT", data.feature_dim, data.num_classes,
+                            fused_gat=fused, **GAT_STACK)
+        params = model.init(0)
+
+        def forward(p, m=model):
+            return m.apply(p, feats, none_i, none_f, agg=agg)
+
+        def loss(p):
+            logits = forward(p)
+            logp = jax.nn.log_softmax(logits[nodes])
+            return -jnp.mean(jnp.take_along_axis(logp, labels[:, None],
+                                                 axis=1)), logits
+        with jax.default_matmul_precision("high"):
+            fwd = jax.jit(forward)(params)
+            (value, logits), grads = jax.jit(
+                jax.value_and_grad(loss, has_aux=True))(params)
+        out[fused] = (np.asarray(fwd), np.asarray(logits), float(value),
+                      grads)
+    # the two paths differ only in the order of the attention's f32 sums:
+    # ~1e-5 of a logit through three layers; 1e-3 of (1 + |logit|) leaves
+    # margin, and a wrong row is off by O(1)
+    order = np.asarray(agg.buckets.order)
+    for prog, k in (("forward", 0), ("gradient", 1)):
+        got, want = out[True][k], out[False][k]
+        err = np.abs(got - want).max(axis=1)
+        bound = 1e-3 * (1.0 + np.abs(want).max(axis=1))
+        start = 0
+        for table in agg.buckets.tables:
+            rows = order[start:start + table.shape[0]]
+            start += table.shape[0]
+            bad = int(np.sum(err[rows] > bound[rows]))
+            print(f"[gat_stack] {prog} bucket w={table.shape[1]} "
+                  f"rows={rows.size} max_err={err[rows].max(initial=0.0):.3e}"
+                  f" bad_rows={bad}")
+            check(bad == 0, f"gat_stack {prog}: {bad} rows of the "
+                            f"{table.shape[1]}-slot bucket differ between "
+                            f"kernel and XLA")
+    # a correct loss agrees to ~1e-6; the kernel's wrong correction rows
+    # once moved it by 1e-2
+    loss_err = abs(out[True][2] - out[False][2]) / abs(out[False][2])
+    grad_err = max(float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+                   for a, b in zip(jax.tree_util.tree_leaves(out[True][3]),
+                                   jax.tree_util.tree_leaves(out[False][3])))
+    print(f"[gat_stack] batch={batch} loss_rel_err={loss_err:.3e} "
+          f"worst_leaf_grad_rel_err={grad_err:.3e}")
+    check(loss_err <= 1e-4 and grad_err <= 1e-3,
+          "gat_stack: the batch loss or its gradient differs between "
+          "kernel and XLA")
 
 
 def _spans(name: str, arr, devices) -> None:
@@ -329,6 +406,9 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the shard_map-vs-vmap phase on a "
                          "four-chip mesh")
+    ap.add_argument("--phase", choices=("all", "gat_stack"), default="all",
+                    help="gat_stack: on one chip, run only the GAT stack's "
+                         "kernel-against-XLA comparison")
     args = ap.parse_args(argv)
 
     t_start = time.perf_counter()
@@ -341,12 +421,15 @@ def main(argv=None) -> int:
                         hidden_dim=HIDDEN)
     if args.chips == 4:
         multi_chip_phase(data, model, devices[:4], meter)
+    elif args.phase == "gat_stack":
+        gat_stack_phase(data)
     else:
         train(data, model, make_plan(machines=8, rounds=5), meter, "train")
         train(data, model, make_plan(machines=8, rounds=2,
                                      compression="int8_ef"),
               meter, "train_int8_ef")
         kernel_phase(data)
+        gat_stack_phase(data)
     print(f"[done] total_s={time.perf_counter() - t_start:.1f}")
     d0 = devices[0]
     print(json.dumps({"ok": True, "device": {

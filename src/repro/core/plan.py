@@ -81,7 +81,6 @@ from repro.core.engine import (
 from repro.core.machine import make_eval_fn, make_machine_step
 from repro.core.schedules import KBucketing, local_epoch_schedule
 from repro.data.graph_loader import make_shard_loaders, sample_round
-from repro.graph.csr import build_neighbor_table
 from repro.graph.datasets import SyntheticDataset
 from repro.graph.halo import build_halo_plan, build_halo_program, ext_fanout
 from repro.graph.partition import PARTITION_METHODS, partition_graph
@@ -626,17 +625,12 @@ class RoundSampler:
         self.eval_fn = make_eval_fn(model)
 
         # full-graph full-neighbor operands for eval + correction: the
-        # degree buckets; the single (N, max_deg) table only where a layer
-        # reads it besides (GAT), else a zero-width stand-in
+        # degree buckets, which every model reads; the padded table they
+        # replace is a zero-width stand-in
         self.full_feats = jnp.asarray(data.features)
         self.full_labels = jnp.asarray(data.labels)
-        if model.reads_full_table:
-            full_table, full_mask = build_neighbor_table(data.graph)
-        else:
-            full_table = np.zeros((data.num_nodes, 0), np.int32)
-            full_mask = np.zeros((data.num_nodes, 0), np.float32)
-        self.full_table_j = jnp.asarray(full_table)
-        self.full_mask_j = jnp.asarray(full_mask)
+        self.full_table_j = jnp.zeros((data.num_nodes, 0), jnp.int32)
+        self.full_mask_j = jnp.zeros((data.num_nodes, 0), jnp.float32)
         self.full_agg = bucketed_operands(data.graph)
         self.full_agg_stats = full_table_stats(data.graph)
 
@@ -680,6 +674,30 @@ class RoundSampler:
         self._device_round_jit = jax.jit(
             _device_round,
             static_argnames=("num_steps", "width", "batch_size"))
+
+    def attention_stats(self) -> Dict:
+        """GAT's engagement: slots its attention reads per round in the
+        forward passes of the local phase (P·K steps, every layer, every
+        padded local row over ``fanout`` slots) and of the server side (S
+        correction steps and the evaluation, every layer, over the degree
+        buckets), the node's own slot included with ``self_loop``; the XLA
+        attention's chunk in bytes and whether the kernel runs.  Empty for
+        other models."""
+        model = self.model
+        if model.arch != "GAT":
+            return {}
+        from repro.kernels.ops import ATTENTION_CHUNK_BYTES
+        own = int(model.self_loop)
+        bucket_slots = sum(t.shape[0] * (t.shape[1] + own)
+                           for t in self.full_agg.buckets.tables)
+        loc, srv = self.plan.local, self.plan.server
+        return {"gat_local_slots": (self.num_machines * loc.local_k
+                                    * model.num_layers * self.n_max
+                                    * (self.fanout + own)),
+                "gat_server_slots": ((srv.correction_steps + 1)
+                                     * model.num_layers * bucket_slots),
+                "gat_chunk_bytes": ATTENTION_CHUNK_BYTES,
+                "gat_kernel": bool(model.fused_gat)}
 
     @property
     def num_sampler_retraces(self) -> int:
@@ -1359,7 +1377,7 @@ class PlanTrainer:
                       "sampler_placement": sampler.placement,
                       "sampler_overlap": plan.sampler.resolved_overlap,
                       "corr_agg_layout": sampler.corr_agg_layout,
-                      **sampler.full_agg_stats}
+                      **sampler.full_agg_stats, **sampler.attention_stats()}
         if any(d.kind == "ext" for d in self.descs):
             meta.update({
                 "halo_executed": not plan.comm.host_halo,

@@ -2,8 +2,8 @@
 
 * :mod:`repro.kernels.spmm`         — block-sparse (BCSR) SpMM for full-graph
   neighbor aggregation (the GNN hotspot; used by server correction / GGS).
-* :mod:`repro.kernels.edge_softmax` — fused masked softmax-weighted
-  aggregation for GAT.
+* :mod:`repro.kernels.edge_softmax` — GAT's attention-weighted sum of
+  each row's neighbour rows, per head, gathered by the kernel itself.
 * :mod:`repro.kernels.linear_scan`  — chunked linear-attention/SSM scan with
   data-dependent vector decay (Mamba2 SSD and RWKV6 share this core).
 * :mod:`repro.kernels.quantize`     — row-wise stochastic-rounding int8

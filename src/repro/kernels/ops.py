@@ -9,14 +9,16 @@ swaps it in without being asked.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.graph.csr import CSRGraph
 from repro.kernels import ref
-from repro.kernels.edge_softmax import edge_softmax
+from repro.kernels.edge_softmax import block_rows, gat_attention, lane_rows
 from repro.kernels.linear_scan import linear_scan_chunked
 from repro.kernels.quantize import dequantize_rows, quantize_rows
 from repro.kernels.spmm import build_bcsr, spmm_bcsr
@@ -88,52 +90,148 @@ def spmm_aggregate(graph: CSRGraph, h: jnp.ndarray,
 
 
 # --------------------------------------------------------------------------
-# GAT fused edge softmax
+# GAT edge attention
 # --------------------------------------------------------------------------
+#: gathered slab bytes one chunk of the XLA attention (the backward, and the
+#: forward without the kernel) may hold, per call: a vmap over P machines
+#: runs P chunks at once
+ATTENTION_CHUNK_BYTES = 32 << 20
+
+
+def attention_chunk_rows(width: int, cols: int) -> int:
+    """Rows per chunk of the XLA attention: their ``(rows, width, cols)``
+    float32 slab fits :data:`ATTENTION_CHUNK_BYTES`."""
+    return max(1, ATTENTION_CHUNK_BYTES // max(width * cols * 4, 1))
+
+
+def _chunked(table, alpha, rows: int):
+    """``table``/``alpha`` padded to whole chunks of ``rows`` (pad slots
+    read node 0 with weight 0) and split ``(n, rows, ...)``."""
+    r = table.shape[0]
+    n = -(-r // rows)
+    pad = n * rows - r
+    table = jnp.pad(table, ((0, pad), (0, 0)))
+    alpha = jnp.pad(alpha, ((0, pad), (0, 0), (0, 0)))
+    return (table.reshape(n, rows, *table.shape[1:]),
+            alpha.reshape(n, rows, *alpha.shape[1:]))
+
+
+def _attend_xla(z, alpha, table):
+    """The attention sum in XLA, one chunk of rows at a time: a multiply
+    and a sum, exact float32 (no dot)."""
+    r, width, heads = alpha.shape
+    d = z.shape[1]
+    rows = attention_chunk_rows(width, d)
+
+    def chunk(args):
+        tab, a = args
+        zg = z[tab].reshape(*tab.shape, heads, d // heads)
+        return jnp.sum(a[..., None] * zg, axis=1).reshape(tab.shape[0], d)
+
+    if r <= rows:
+        return chunk((table, alpha))
+    tabs, alphas = _chunked(table, alpha, rows)
+    return jax.lax.map(chunk, (tabs, alphas)).reshape(-1, d)[:r]
+
+
+def _attend_kernel_call(z, alpha, table):
+    """The attention sum through the Pallas kernel
+    (:func:`repro.kernels.edge_softmax.gat_attention`)."""
+    r, width, heads = alpha.shape
+    n, d = z.shape
+    sub = lane_rows(d)
+    z3 = jnp.pad(z.astype(jnp.float32),
+                 ((0, 0), (0, sub * 128 - d))).reshape(n, sub, 128)
+    bn = block_rows(width, sub)
+    pad = (-r) % bn
+    tab = jnp.pad(table, ((0, pad), (0, 0)))
+    a = jnp.pad(alpha.astype(jnp.float32).reshape(r, width * heads),
+                ((0, pad), (0, 0)))
+    out = gat_attention(tab, a, z3, heads=heads, head_dim=d // heads,
+                        block_rows=bn, interpret=pallas_interpret())
+    return out.reshape(r + pad, sub * 128)[:r, :d].astype(z.dtype)
+
+
+@jax.custom_batching.custom_vmap
+def _attend_kernel(z, alpha, table):
+    return _attend_kernel_call(z, alpha, table)
+
+
+@_attend_kernel.def_vmap
+def _attend_kernel_vmap(axis_size, in_batched, z, alpha, table):
+    """Under vmap (the machines of the local phase) one kernel call over
+    every machine's rows: the tables are offset into the machines' stacked
+    ``z`` rows."""
+    z_b, a_b, t_b = in_batched
+    lead = lambda x, b: x if b else jnp.broadcast_to(  # noqa: E731
+        x, (axis_size, *x.shape))
+    alpha, table = lead(alpha, a_b), lead(table, t_b)
+    if z_b:
+        n = z.shape[1]
+        table = table + (jnp.arange(axis_size, dtype=table.dtype)
+                         * n)[:, None, None]
+        z = z.reshape(axis_size * n, z.shape[2])
+    r = table.shape[1]
+    out = _attend_kernel(z, alpha.reshape(axis_size * r, *alpha.shape[2:]),
+                         table.reshape(axis_size * r, table.shape[2]))
+    return out.reshape(axis_size, r, out.shape[-1]), True
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def attend(z, alpha, table, fused):
+    """``out[r, c] = Σ_j alpha[r, j, c // F] · z[table[r, j], c]``: the
+    attention-weighted sum of each row's gathered neighbour rows, per head
+    (``alpha (R, W, H)``, ``z (N, H·F)``).  The forward runs the Pallas
+    kernel with ``fused`` and the chunked XLA sum without; the backward is
+    the chunked XLA recompute.  Neither holds more of the gathered slab
+    than one chunk (:data:`ATTENTION_CHUNK_BYTES`), the kernel none."""
+    return (_attend_kernel if fused else _attend_xla)(z, alpha, table)
+
+
+def _attend_fwd(z, alpha, table, fused):
+    return attend(z, alpha, table, fused), (z, alpha, table)
+
+
+def _attend_bwd(fused, res, g):
+    z, alpha, table = res
+    r, width, heads = alpha.shape
+    n, d = z.shape
+    rows = min(attention_chunk_rows(width, d), r)
+    tabs, alphas = _chunked(table, alpha, rows)
+    gs = jnp.pad(g, ((0, tabs.shape[0] * rows - r), (0, 0))).reshape(
+        tabs.shape[0], rows, heads, d // heads)
+
+    def chunk(dz, args):
+        tab, a, gc = args
+        zg = z[tab].reshape(*tab.shape, heads, d // heads)
+        da = jnp.sum(zg * gc[:, None], axis=-1)                  # (c, W, H)
+        upd = (a[..., None] * gc[:, None]).reshape(-1, d)
+        return dz.at[tab.reshape(-1)].add(upd), da
+
+    dz, da = jax.lax.scan(chunk, jnp.zeros_like(z), (tabs, alphas, gs))
+    da = da.reshape(-1, width, heads)[:r]
+    return (dz, da.astype(alpha.dtype),
+            np.zeros(np.shape(table), jax.dtypes.float0))
+
+
+attend.defvjp(_attend_fwd, _attend_bwd)
+
+
 def edge_softmax_aggregate(scores: jnp.ndarray, mask: jnp.ndarray,
-                           vals: jnp.ndarray, use_ref: bool = False,
-                           block_n: int = 128, block_d: int = 128) -> jnp.ndarray:
-    """out[n] = Σ_f softmax_f(scores)·vals — fused GAT aggregation.
-
-    Computes in f32 inside the kernel, returns ``vals.dtype`` so the op is
-    dtype-preserving and call sites need no cast.
-    """
-    n, f = scores.shape
-    d = vals.shape[-1]
+                           z: jnp.ndarray, table: jnp.ndarray,
+                           fused: bool = True,
+                           use_ref: bool = False) -> jnp.ndarray:
+    """GAT aggregation of each table row: the masked softmax of ``scores
+    (R, W, H)`` over the row's slots, weighting the slots' rows of ``z
+    (N, H·F)`` per head; ``(R, H·F)``.  A row with no valid slot comes out
+    zero.  The softmax runs in XLA on the ``(R, W, H)`` scores; the
+    weighted sum is :func:`attend` (the kernel with ``fused``)."""
     if use_ref:
-        return ref.edge_softmax_ref(scores, mask, vals).astype(vals.dtype)
-    bn = min(block_n, max(8, 1 << (n - 1).bit_length()))
-    bd = min(block_d, max(8, 1 << (d - 1).bit_length()))
-    s = _pad_to(scores, 0, bn)
-    m = _pad_to(mask, 0, bn)
-    v = _pad_to(_pad_to(vals, 0, bn), 2, bd)
-    out = edge_softmax(s, m, v, block_n=bn, block_d=bd,
-                       interpret=pallas_interpret())
-    return out[:n, :d].astype(vals.dtype)
-
-
-@jax.custom_vjp
-def edge_softmax_aggregate_trainable(scores, mask, vals):
-    """Differentiable fused edge-softmax: Pallas kernel forward, oracle-VJP
-    backward — the standard pattern for kernels without a hand-written
-    backward.  Used by the GNN GAT layer when ``fused_gat=True``."""
-    return edge_softmax_aggregate(scores, mask, vals)
-
-
-def _esa_fwd(scores, mask, vals):
-    return edge_softmax_aggregate(scores, mask, vals), (scores, mask, vals)
-
-
-def _esa_bwd(res, g):
-    scores, mask, vals = res
-    _, vjp = jax.vjp(ref.edge_softmax_ref, scores, mask, vals)
-    ds, dm, dv = vjp(g.astype(jnp.float32))
-    # the oracle computes in f32; cotangents must match the primal dtypes
-    return (ds.astype(scores.dtype), jnp.zeros_like(mask),
-            dv.astype(vals.dtype))
-
-
-edge_softmax_aggregate_trainable.defvjp(_esa_fwd, _esa_bwd)
+        return ref.edge_softmax_ref(scores, mask, z, table).astype(z.dtype)
+    valid = mask[..., None] > 0
+    alpha = jax.nn.softmax(jnp.where(valid, scores, -1e30), axis=1)
+    alpha = alpha * mask[..., None].astype(alpha.dtype)
+    return attend(z, alpha.astype(z.dtype), table, fused)
 
 
 # --------------------------------------------------------------------------

@@ -45,15 +45,19 @@ def spmm_bcsr_ref(tile_cols: jnp.ndarray, tile_vals: jnp.ndarray,
 # GAT fused masked softmax-weighted aggregation
 # --------------------------------------------------------------------------
 def edge_softmax_ref(scores: jnp.ndarray, mask: jnp.ndarray,
-                     vals: jnp.ndarray) -> jnp.ndarray:
-    """out[n] = Σ_f softmax_f(scores[n])·vals[n,f]  with masked slots.
+                     z: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
+    """out[r, k] = Σ_j softmax_j(scores[r, ·, k])·z[table[r, j], k], masked.
 
-    scores: (N, F); mask: (N, F) {0,1}; vals: (N, F, D).
-    Rows with zero mask produce zeros (matches the GNN layer semantics).
+    scores: (R, W, H); mask: (R, W) {0,1}; z: (N, H·F), head k's columns
+    ``k·F .. (k+1)·F``; table: (R, W) node ids.  Rows with zero mask produce
+    zeros (matches the GNN layer semantics).  Gathers the whole
+    ``(R, W, H·F)`` slab: the oracle, not the path.
     """
-    s = jnp.where(mask > 0, scores.astype(jnp.float32), -1e30)
-    alpha = jax.nn.softmax(s, axis=-1) * mask
-    return jnp.einsum("nf,nfd->nd", alpha, vals.astype(jnp.float32))
+    r, w, h = scores.shape
+    s = jnp.where(mask[..., None] > 0, scores.astype(jnp.float32), -1e30)
+    alpha = jax.nn.softmax(s, axis=1) * mask[..., None]
+    zg = z.astype(jnp.float32)[table].reshape(r, w, h, -1)
+    return jnp.sum(alpha[..., None] * zg, axis=1).reshape(r, -1)
 
 
 # --------------------------------------------------------------------------

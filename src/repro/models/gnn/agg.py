@@ -22,9 +22,9 @@ baked-in lowering:
     Full-graph aggregation through the Pallas BCSR SpMM
     (:func:`repro.kernels.spmm.spmm_bcsr`) with an unnormalized-adjacency
     operand (symmetric, so the ``custom_vjp`` backward reuses the same
-    tiles); the GAT softmax-aggregate routes through the fused Pallas
-    edge-softmax kernel; interpreted on the CPU backend, compiled on a
-    TPU (:func:`repro.kernels.ops.pallas_interpret`).
+    tiles); GAT's attention runs over the degree buckets (below), which
+    these operands carry too; interpreted on the CPU backend, compiled on
+    a TPU (:func:`repro.kernels.ops.pallas_interpret`).
 
 ``layout="auto"``
     :func:`choose_layout` picks per (graph, table width, sampling) via a
@@ -40,9 +40,10 @@ Degree buckets (:func:`degree_buckets`, layout ``"bucketed"``)
     ``(N_b, w_b)`` table per bucket, and one permutation back to node
     order.  Each row sums the same neighbors in the same slot order as the
     single ``max_deg``-wide table; only all-padding slots are dropped.  Not
-    a user option: the full-neighbor mean/sym consumers always take it.  A
-    near-regular graph comes out as one bucket in node order, which is the
-    single table and its program.
+    a user option: every full-neighbor consumer takes it, GAT's attention
+    (:func:`bucketed_gat_aggregate`) included.  A near-regular graph comes
+    out as one bucket in node order, which is the single table and its
+    program.
 
 Operands are prebuilt host-side once per graph and cached on the graph
 object (the ``_all_nodes_plan`` / ``RoundSampler.prewarm`` idiom), so no
@@ -270,7 +271,8 @@ def build_agg_operands(graph: CSRGraph, layout: str,
     if layout == "bcsr_kernel":
         return AggOperands("bcsr_kernel",
                            edges=edge_operands(graph, num_segments),
-                           bcsr=bcsr_operands(graph))
+                           bcsr=bcsr_operands(graph),
+                           buckets=degree_buckets(graph))
     raise ValueError(f"unknown aggregation layout {layout!r}; "
                      f"choose one of {LAYOUTS}")
 
@@ -420,45 +422,85 @@ def csr_sym_aggregate(h: jnp.ndarray, edges: EdgeCSR,
     return edge_weighted_sum(h, edges.seg, edges.nbr, w, edges.num_segments)
 
 
-def csr_gat_aggregate(z: jnp.ndarray, src_score: jnp.ndarray,
-                      dst_score: jnp.ndarray, edges: EdgeCSR,
-                      negative_slope: float = 0.2) -> jnp.ndarray:
-    """Edge-centric masked GAT softmax-aggregate.
+def csr_gat_aggregate(z: jnp.ndarray, s_src: jnp.ndarray,
+                      s_dst: jnp.ndarray, edges: EdgeCSR, *,
+                      negative_slope: float = 0.2,
+                      self_loop: bool = False, **_) -> jnp.ndarray:
+    """Edge-centric masked GAT softmax-aggregate, per head.
 
-    Per-edge scores, a ``segment_max``-stabilized softmax over each node's
-    real edges, then the weighted segment-sum — all E-sized.  Zero-degree
-    rows emit zeros, matching the padded path's all-pad-row convention.
-    Differentiable in ``z`` and the scores through jax's segment ops (their
-    transposes are already edge-centric gathers).
+    ``z (N, H·F)``, scores ``s_src``/``s_dst (N, H)``: edge ``i ← j`` scores
+    ``LeakyReLU(s_dst[i] + s_src[j])``; a ``segment_max``-stabilized
+    softmax over each node's real edges (and itself, with ``self_loop``),
+    then the weighted segment-sum — all E-sized.  Zero-degree rows without
+    the self term emit zeros, matching the padded path's all-pad-row
+    convention.  Differentiable in ``z`` and the scores through jax's
+    segment ops (their transposes are already edge-centric gathers).
     """
     seg, nbr, emask, ns = edges.seg, edges.nbr, edges.emask, edges.num_segments
+    if self_loop:
+        own = jnp.arange(ns, dtype=seg.dtype)
+        seg, nbr = jnp.concatenate([seg, own]), jnp.concatenate([nbr, own])
+        emask = jnp.concatenate([emask, jnp.ones((ns,), emask.dtype)])
+    heads = s_src.shape[1]
     segc = jnp.minimum(seg, ns - 1)
-    e = src_score[segc] + dst_score[nbr]
-    e = jax.nn.leaky_relu(e, negative_slope)
+    e = jax.nn.leaky_relu(s_dst[segc] + s_src[nbr], negative_slope)
+    valid = (emask > 0)[:, None]
     neg = jnp.asarray(-1e30, e.dtype)
-    m = jax.ops.segment_max(jnp.where(emask > 0, e, neg), seg,
-                            num_segments=ns)
+    m = jax.ops.segment_max(jnp.where(valid, e, neg), seg, num_segments=ns)
     # softmax shift: constant per segment, gradient cancels — and clamping
     # keeps zero-degree rows (max = -inf) finite
     m = jax.lax.stop_gradient(jnp.maximum(m, neg))
-    num = jnp.exp(e - m[segc]) * emask.astype(e.dtype)
-    den = jax.ops.segment_sum(num, seg, num_segments=ns)
-    out = jax.ops.segment_sum(num[:, None] * z[nbr], seg, num_segments=ns)
-    return out / jnp.maximum(den, 1e-30)[:, None]
+    num = jnp.exp(e - m[segc]) * emask.astype(e.dtype)[:, None]
+    den = jax.ops.segment_sum(num, seg, num_segments=ns)         # (N, H)
+    zj = z[nbr].reshape(nbr.shape[0], heads, -1)
+    out = jax.ops.segment_sum(num[..., None] * zj, seg, num_segments=ns)
+    out = out / jnp.maximum(den, 1e-30)[..., None]
+    return out.reshape(ns, -1)
+
+
+def gat_aggregate(z: jnp.ndarray, s_src: jnp.ndarray, s_dst: jnp.ndarray,
+                  table: jnp.ndarray, mask: jnp.ndarray,
+                  rows: Optional[jnp.ndarray] = None, *,
+                  negative_slope: float = 0.2, self_loop: bool = False,
+                  fused: bool = False) -> jnp.ndarray:
+    """GAT attention of each row of a dense index table, per head:
+    ``(R, H·F)``.
+
+    ``table``/``mask (R, w)`` hold row r's neighbour slots; ``rows (R,)``
+    its node id (``None``: row r is node r).  Slot ``i ← j`` scores
+    ``LeakyReLU(s_dst[i] + s_src[j])``; with ``self_loop`` node i itself is
+    one more slot of its row (a column of the call's table, not an edge of
+    the graph).  A masked softmax over the slots weights ``z``'s rows
+    through :func:`repro.kernels.ops.edge_softmax_aggregate`: the Pallas
+    kernel gathers them with ``fused``, the chunked XLA sum without; no
+    ``(R, w, H·F)`` slab is held either way.
+    """
+    r = table.shape[0]
+    if rows is None:
+        rows = jnp.arange(r, dtype=jnp.int32)
+    if self_loop:
+        table = jnp.concatenate([rows[:, None].astype(table.dtype), table],
+                                axis=1)
+        mask = jnp.concatenate([jnp.ones((r, 1), mask.dtype), mask], axis=1)
+    if table.shape[1] == 0:
+        return jnp.zeros((r, z.shape[1]), z.dtype)
+    from repro.kernels.ops import edge_softmax_aggregate
+    e = jax.nn.leaky_relu(s_dst[rows][:, None, :] + s_src[table],
+                          negative_slope)                         # (R, w, H)
+    return edge_softmax_aggregate(e, mask, z, table, fused=fused)
 
 
 # --------------------------------------------------------------------------
 # Degree-bucket primitives (bucketed layout)
 # --------------------------------------------------------------------------
-def _bucketed(h: jnp.ndarray, buckets: DegreeBuckets, reduce) -> jnp.ndarray:
-    """``reduce(h[tab_b], tab_b, mask_b, rows_b)`` per bucket, back in node
-    order.  ``rows_b`` are a bucket's node ids; a width-0 bucket's rows sum
-    nothing and come out zero, as an all-padding row of the single table
-    does."""
+def _bucketed(buckets: DegreeBuckets, reduce) -> jnp.ndarray:
+    """``reduce(tab_b, mask_b, rows_b)`` per bucket, back in node order.
+    ``rows_b`` are a bucket's node ids; a width-0 bucket's rows sum nothing
+    and come out zero, as an all-padding row of the single table does."""
     parts, start = [], 0
     for tab, mask in zip(buckets.tables, buckets.masks):
         rows = buckets.order[start:start + tab.shape[0]]
-        parts.append(reduce(h[tab], tab, mask, rows))
+        parts.append(reduce(tab, mask, rows))
         start += tab.shape[0]
     if len(parts) == 1:              # one bucket holds every node in order
         return parts[0]
@@ -469,19 +511,28 @@ def bucketed_mean_aggregate(h: jnp.ndarray,
                             buckets: DegreeBuckets) -> jnp.ndarray:
     """The padded mean over each bucket's ``w_b`` slots: masked sum over
     the mask sum, as the single table computes it."""
-    def reduce(gathered, tab, mask, rows):
-        s = jnp.einsum("nfd,nf->nd", gathered, mask)
+    def reduce(tab, mask, rows):
+        s = jnp.einsum("nfd,nf->nd", h[tab], mask)
         return s / jnp.clip(mask.sum(-1, keepdims=True), 1.0, None)
-    return _bucketed(h, buckets, reduce)
+    return _bucketed(buckets, reduce)
 
 
 def bucketed_sym_aggregate(h: jnp.ndarray, buckets: DegreeBuckets,
                            normalizers: jnp.ndarray) -> jnp.ndarray:
     """The padded ``Σ_j h_j · nrm_i · nrm_j`` over each bucket's slots."""
-    def reduce(gathered, tab, mask, rows):
+    def reduce(tab, mask, rows):
         coef = mask * normalizers[tab] * normalizers[rows][:, None]
-        return jnp.einsum("nfd,nf->nd", gathered, coef)
-    return _bucketed(h, buckets, reduce)
+        return jnp.einsum("nfd,nf->nd", h[tab], coef)
+    return _bucketed(buckets, reduce)
+
+
+def bucketed_gat_aggregate(z: jnp.ndarray, s_src: jnp.ndarray,
+                           s_dst: jnp.ndarray, buckets: DegreeBuckets,
+                           **kw) -> jnp.ndarray:
+    """:func:`gat_aggregate` over each bucket's ``w_b`` slots, in node
+    order: the full-neighbor attention without the single table."""
+    return _bucketed(buckets, lambda tab, mask, rows: gat_aggregate(
+        z, s_src, s_dst, tab, mask, rows, **kw))
 
 
 # --------------------------------------------------------------------------

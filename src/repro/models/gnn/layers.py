@@ -10,10 +10,10 @@ so the mean aggregation of Eq. 1/3/4 is a dense gather + masked mean, which
 XLA lowers to efficient dynamic-gathers on TPU.  Every aggregate op also
 accepts prebuilt :class:`repro.models.gnn.agg.AggOperands` (``agg=``): the
 ``csr`` layout replaces the ``N·fanout·d`` dense gather with an ``E·d``
-edge-centric segment-sum, ``bcsr_kernel`` routes through the Pallas
-BCSR SpMM / fused edge-softmax kernels — the full-neighbor paths of the
+edge-centric segment-sum, ``bcsr_kernel`` routes the mean/sym
+aggregations through the Pallas BCSR SpMM — the full-neighbor paths of the
 server-correction step and exact serving — and ``bucketed`` splits the
-full-neighbor table by degree (mean/sym only; GAT keeps ``table``/``mask``).
+full-neighbor table by degree (GAT's attention reads the buckets of both).
 ``agg=None`` (the default) is the unchanged padded path.
 """
 from __future__ import annotations
@@ -25,8 +25,8 @@ import jax.numpy as jnp
 
 from repro.models.gnn.agg import (
     AggOperands, bcsr_mean_aggregate, bcsr_sym_aggregate,
-    bucketed_mean_aggregate, bucketed_sym_aggregate, csr_gat_aggregate,
-    csr_mean_aggregate, csr_sym_aggregate,
+    bucketed_gat_aggregate, bucketed_mean_aggregate, bucketed_sym_aggregate,
+    csr_gat_aggregate, csr_mean_aggregate, csr_sym_aggregate, gat_aggregate,
 )
 
 
@@ -87,39 +87,40 @@ def sage_layer(params: Dict, h: jnp.ndarray, table: jnp.ndarray,
 
 
 def gat_layer(params: Dict, h: jnp.ndarray, table: jnp.ndarray,
-              mask: jnp.ndarray, activation=jax.nn.elu,
+              mask: jnp.ndarray, heads: int = 1, self_loop: bool = False,
               negative_slope: float = 0.2, fused: bool = False,
               agg: Optional[AggOperands] = None) -> jnp.ndarray:
-    """Eq. 10/11: masked edge softmax over the padded neighbor slots.
+    """Eq. 10/11 with ``heads`` heads: ``(N, H·F)``, head k in columns
+    ``k·F .. (k+1)·F``, before any bias or activation.
 
-    Single-head formulation (heads are a vmap away and the paper's tables
-    use modest head counts).  ``fused=True`` — or ``agg`` with the
-    ``bcsr_kernel`` layout — routes the softmax-aggregate through the
-    Pallas kernel (``repro.kernels.edge_softmax``) with the oracle-VJP
-    backward, the VMEM-resident path for the correction step's full-graph
-    GAT aggregation.  The ``csr`` layout computes per-edge scores and an
-    edge-centric segment softmax instead of the padded (N, fanout) slots.
+    ``z = h W`` as ``(N, H, F)``; ``s_src = z·a_src`` scores a node as a
+    neighbour, ``s_dst = z·a_dst`` as the row, each ``(N, H)`` in XLA; row
+    i attends over its slots j (and itself, with ``self_loop``) by the
+    masked softmax of ``LeakyReLU(s_dst[i] + s_src[j])`` and sums ``z[j]``
+    per head.  With a residual projection ``params["r"]``, ``h R`` is
+    added.  The slots are the sampled ``table``/``mask``, or the degree
+    buckets that ``agg`` carries (the ``bucketed`` and ``bcsr_kernel``
+    layouts; the full-neighbor forwards), or the edge list of the ``csr``
+    layout.  ``fused=True`` runs the forward's neighbour gather and sum in
+    the Pallas kernel (``repro.kernels.edge_softmax``), which holds the
+    gathered rows in VMEM only.
     """
-    z = h @ params["w"]                           # (N, d')
-    src_score = z @ params["a_src"]               # (N,)
-    dst_score = z @ params["a_dst"]               # (N,)
+    n = h.shape[0]
+    z = h @ params["w"]                                   # (N, H·F)
+    z3 = z.reshape(n, heads, -1)
+    s_src = jnp.sum(z3 * params["a_src"].reshape(heads, -1), axis=-1)
+    s_dst = jnp.sum(z3 * params["a_dst"].reshape(heads, -1), axis=-1)
+    kw = dict(negative_slope=negative_slope, self_loop=self_loop)
     if agg is not None and agg.layout == "csr":
-        out = csr_gat_aggregate(z, src_score, dst_score, agg.edges,
-                                negative_slope)
+        out = csr_gat_aggregate(z, s_src, s_dst, agg.edges, **kw)
+    elif agg is not None and agg.buckets is not None:
+        out = bucketed_gat_aggregate(z, s_src, s_dst, agg.buckets,
+                                     fused=fused, **kw)
     else:
-        e = src_score[:, None] + dst_score[table]     # (N, fanout)
-        e = jax.nn.leaky_relu(e, negative_slope)
-        if fused or (agg is not None and agg.layout == "bcsr_kernel"):
-            from repro.kernels.ops import edge_softmax_aggregate_trainable
-            out = edge_softmax_aggregate_trainable(e, mask, z[table])
-        else:
-            e = jnp.where(mask > 0, e, -1e30)
-            alpha = jax.nn.softmax(e, axis=-1)
-            alpha = alpha * mask                      # rows with no nbrs → all-pad
-            out = jnp.einsum("nf,nfd->nd", alpha, z[table])
-    if "b" in params:
-        out = out + params["b"]
-    return activation(out) if activation is not None else out
+        out = gat_aggregate(z, s_src, s_dst, table, mask, fused=fused, **kw)
+    if "r" in params:
+        out = out + h @ params["r"]
+    return out
 
 
 def linear_layer(params: Dict, h: jnp.ndarray, *_, activation=None,

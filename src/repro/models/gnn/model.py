@@ -36,7 +36,17 @@ class GNNModel:
     num_classes: int
     appnp_steps: int = 10
     appnp_beta: float = 0.1
-    fused_gat: bool = False   # route GAT aggregation through the Pallas kernel
+    fused_gat: bool = False   # GAT's neighbour gather+sum in the Pallas kernel
+    # the GAT stack (``hidden_dim`` is the width of one head): its layers,
+    # heads per layer (concatenated between layers, averaged at the last),
+    # a residual projection h R per layer, the node itself as one more slot
+    # of its attention, and between layers BatchNorm then ReLU (without:
+    # a bias then ELU).  The defaults are the two-layer single-head GAT.
+    num_layers: int = 2
+    num_heads: int = 1
+    residual: bool = False
+    self_loop: bool = False
+    batch_norm: bool = False
     # default aggregation layout for full-graph consumers (serving backends
     # read this when not overridden); "padded" | "csr" | "bcsr_kernel" |
     # "auto" — see repro.models.gnn.agg
@@ -47,6 +57,14 @@ class GNNModel:
         if self.agg_layout not in LAYOUTS:
             raise ValueError(f"unknown agg_layout {self.agg_layout!r}; "
                              f"choose one of {LAYOUTS}")
+        stack = {"num_layers": 2, "num_heads": 1, "residual": False,
+                 "self_loop": False, "batch_norm": False}
+        if self.arch != "GAT" and any(getattr(self, k) != v
+                                      for k, v in stack.items()):
+            raise ValueError(f"{sorted(stack)} shape the GAT stack only, "
+                             f"not {self.arch!r}")
+        if self.num_layers < 1 or self.num_heads < 1:
+            raise ValueError("a GAT stack needs a layer and a head")
 
     # ------------------------------------------------------------------ init
     def init(self, seed: int = 0) -> Dict:
@@ -54,16 +72,7 @@ class GNNModel:
         params: Dict[str, Dict] = {}
         dims = self._dims()
         if self.arch == "GAT":
-            d_in, d_h = self.feature_dim, self.hidden_dim
-            params["gat0"] = {"w": _glorot(rng, (d_in, d_h)),
-                              "a_src": _glorot(rng, (d_h,)),
-                              "a_dst": _glorot(rng, (d_h,)),
-                              "b": np.zeros(d_h, np.float32)}
-            params["gat1"] = {"w": _glorot(rng, (d_h, self.num_classes)),
-                              "a_src": _glorot(rng, (self.num_classes,)),
-                              "a_dst": _glorot(rng, (self.num_classes,)),
-                              "b": np.zeros(self.num_classes, np.float32)}
-            return jax.tree_util.tree_map(jnp.asarray, params)
+            return jax.tree_util.tree_map(jnp.asarray, self._init_gat(rng))
         if self.arch == "APPNP":
             d_in, d_h = self.feature_dim, self.hidden_dim
             params["lin0"] = {"w": _glorot(rng, (d_in, d_h)),
@@ -90,6 +99,36 @@ class GNNModel:
                 raise ValueError(f"unknown op {op!r} in arch {self.arch!r}")
         return jax.tree_util.tree_map(jnp.asarray, params)
 
+    def _init_gat(self, rng) -> Dict:
+        """Glorot draws per layer in the order ``w`` (d_in, H·F), ``r``
+        (the same shape, with ``residual``), ``a_dst`` then ``a_src``
+        ((H, F), or (F,) for one head); F is ``hidden_dim``, the classes at
+        the last layer.  Between layers ``bn<l>`` (gamma 1, beta 0) with
+        ``batch_norm``, else a zero bias ``b``; a zero bias ``b`` at the
+        last layer."""
+        params: Dict[str, Dict] = {}
+        heads, d = self.num_heads, self.feature_dim
+        for layer in range(self.num_layers):
+            last = layer == self.num_layers - 1
+            f = self.num_classes if last else self.hidden_dim
+            a_shape = (f,) if heads == 1 else (heads, f)
+            p = {"w": _glorot(rng, (d, heads * f))}
+            if self.residual:
+                p["r"] = _glorot(rng, (d, heads * f))
+            p["a_dst"] = _glorot(rng, a_shape)
+            p["a_src"] = _glorot(rng, a_shape)
+            if last:
+                p["b"] = np.zeros(f, np.float32)
+            elif self.batch_norm:
+                params[f"bn{layer}"] = {
+                    "gamma": np.ones(heads * f, np.float32),
+                    "beta": np.zeros(heads * f, np.float32)}
+            else:
+                p["b"] = np.zeros(heads * f, np.float32)
+            params[f"gat{layer}"] = p
+            d = heads * f
+        return params
+
     def num_message_hops(self) -> int:
         """Graph-aggregation depth L: how far information travels.
 
@@ -99,18 +138,10 @@ class GNNModel:
         Linear/BatchNorm ops are pointwise and contribute nothing.
         """
         if self.arch == "GAT":
-            return 2
+            return self.num_layers
         if self.arch == "APPNP":
             return self.appnp_steps
         return sum(1 for op in self.arch if op in ("G", "S"))
-
-    @property
-    def reads_full_table(self) -> bool:
-        """Whether a full-neighbor forward reads the padded ``table`` and
-        ``mask`` whatever ``agg`` it is given: GAT's attention scores each
-        slot and ignores the degree buckets; the mean aggregations of the
-        other layers take the buckets instead."""
-        return self.arch == "GAT"
 
     def _dims(self) -> List[Tuple[int, int]]:
         """(d_in, d_out) per op; BatchNorm keeps width."""
@@ -136,10 +167,7 @@ class GNNModel:
         (edge-centric / Pallas-kernel layouts for full-neighbor tables);
         ``None`` is the unchanged padded-table path."""
         if self.arch == "GAT":
-            h = L.gat_layer(params["gat0"], feats, table, mask,
-                            fused=self.fused_gat, agg=agg)
-            return L.gat_layer(params["gat1"], h, table, mask,
-                               activation=None, fused=self.fused_gat, agg=agg)
+            return self._apply_gat(params, feats, table, mask, agg)
         if self.arch == "APPNP":
             h = jax.nn.relu(L.linear_layer(params["lin0"], feats))
             h = L.linear_layer(params["lin1"], h)
@@ -162,6 +190,25 @@ class GNNModel:
             elif op == "B":
                 h = L.batch_norm(params[name], h)
         return h
+
+
+    def _apply_gat(self, params, h, table, mask, agg):
+        """The GAT stack: per layer :func:`~repro.models.gnn.layers.
+        gat_layer`; between layers concat → BatchNorm → ReLU with
+        ``batch_norm``, else concat → + b → ELU; the last layer's heads
+        averaged, + b."""
+        heads, n = self.num_heads, h.shape[0]
+        for layer in range(self.num_layers):
+            p = params[f"gat{layer}"]
+            out = L.gat_layer(p, h, table, mask, heads=heads,
+                              self_loop=self.self_loop, fused=self.fused_gat,
+                              agg=agg)
+            if layer == self.num_layers - 1:
+                return out.reshape(n, heads, -1).mean(axis=1) + p["b"]
+            if self.batch_norm:
+                h = jax.nn.relu(L.batch_norm(params[f"bn{layer}"], out))
+            else:
+                h = jax.nn.elu(out + p["b"])
 
 
 def build_model(arch: str, feature_dim: int, num_classes: int,

@@ -333,10 +333,10 @@ def test_kernel_wrappers_preserve_dtype(dtype):
 def test_fused_gat_preserves_dtype(skewed):
     data, table, mask = skewed
     scores = jnp.asarray(np.random.default_rng(0).standard_normal(
-        table.shape).astype(np.float32))
-    vals = jnp.asarray(np.random.default_rng(1).standard_normal(
-        (*table.shape, 6)))
+        (*table.shape, 2)).astype(np.float32))
+    z = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (table.shape[0], 6)))
     for dt in (jnp.float32, jnp.bfloat16):
-        out = edge_softmax_aggregate(scores.astype(dt), mask,
-                                     vals.astype(dt))
+        out = edge_softmax_aggregate(scores.astype(dt), mask, z.astype(dt),
+                                     table)
         assert out.dtype == dt

@@ -22,10 +22,9 @@ from repro.graph.csr import (
 from repro.graph.datasets import grid_graph, rmat_graph, sbm_graph
 from repro.models.gnn import layers as L
 from repro.models.gnn.agg import (
-    bucket_widths, bucketed_operands, choose_layout, degree_buckets,
-    full_table_stats,
+    AggOperands, DegreeBuckets, bucket_widths, bucketed_operands,
+    choose_layout, degree_buckets, full_table_stats,
 )
-from repro.models.gnn.model import GNNModel
 from repro.models.gnn.model import build_model
 from repro.optim import make_optimizer
 
@@ -247,22 +246,23 @@ def test_plan_evaluation_and_correction_match_single_table(sbm):
 
 
 def test_gat_plan_keeps_the_single_table(sbm):
-    """GAT scores every slot of the single table and ignores the buckets,
-    so its evaluation holds the table and gives the same numbers with or
-    without the bucketed operands."""
+    """GAT's attention reads the degree buckets as the mean aggregations
+    do: its plan holds only the zero-width stand-in, and its evaluation on
+    the buckets gives the numbers of the single table."""
     data, _, plan = sbm
     model = build_model("GAT", data.feature_dim, data.num_classes,
                         hidden_dim=8)
     sampler = RoundSampler(data, model, plan)
+    assert sampler.full_table_j.shape == (data.num_nodes, 0)
     table, mask = _single_table(data)
-    np.testing.assert_array_equal(np.asarray(sampler.full_table_j),
-                                  np.asarray(table))
-    args = (model.init(0), sampler.full_feats, sampler.full_table_j,
-            sampler.full_mask_j, sampler.full_labels,
-            jnp.asarray(data.val_nodes))
-    np.testing.assert_array_equal(
-        np.asarray(sampler.eval_fn(*args, sampler.full_agg)),
-        np.asarray(sampler.eval_fn(*args)))
+    head = (model.init(0), sampler.full_feats)
+    tail = (sampler.full_labels, jnp.asarray(data.val_nodes))
+    np.testing.assert_allclose(
+        np.asarray(sampler.eval_fn(*head, sampler.full_table_j,
+                                   sampler.full_mask_j, *tail,
+                                   sampler.full_agg)),
+        np.asarray(sampler.eval_fn(*head, table, mask, *tail)),
+        rtol=1e-5, atol=1e-6)
 
 
 def test_plan_auto_layout_keeps_the_buckets():
@@ -294,9 +294,14 @@ def test_plan_counts_full_aggregation_slots(sbm, monkeypatch):
     assert hist.meta["full_agg_edges"] == int(deg.sum())
     assert hist.meta["full_agg_buckets"] == np.unique(width).size
 
-    # the same plan on the single table: same evaluation and correction
-    monkeypatch.setattr(plan_mod, "bucketed_operands", lambda g: None)
-    monkeypatch.setattr(GNNModel, "reads_full_table", property(lambda m: True))
+    # the same plan on the single table, as one bucket of every node in
+    # node order: same evaluation and correction
+    table, mask = _single_table(data)
+    n = np.arange(data.num_nodes, dtype=np.int32)
+    single = AggOperands("bucketed", buckets=DegreeBuckets(
+        tables=(table,), masks=(mask,), order=jnp.asarray(n),
+        slot_of=jnp.asarray(n)))
+    monkeypatch.setattr(plan_mod, "bucketed_operands", lambda g: single)
     one = build_trainer(data, model, plan).run()
     np.testing.assert_allclose(hist.train_loss, one.train_loss, rtol=1e-5)
     np.testing.assert_allclose(hist.val_score, one.val_score, rtol=1e-5)

@@ -63,38 +63,51 @@ def test_spmm_bcsr_reconstructs_dense_matmul():
 # --------------------------------------------------------------------------
 # Edge softmax
 # --------------------------------------------------------------------------
+def _attention_case(rng, n, f, d, heads, dtype=jnp.float32):
+    """Scores (n, f, heads), mask (n, f), z (n + 5, heads·d) and a table of
+    ids into z's rows."""
+    scores = jnp.asarray(rng.standard_normal((n, f, heads)), dtype)
+    mask = jnp.asarray((rng.random((n, f)) > 0.3).astype(np.float32))
+    z = jnp.asarray(rng.standard_normal((n + 5, heads * d)), dtype)
+    table = jnp.asarray(rng.integers(0, n + 5, (n, f)), jnp.int32)
+    return scores, mask, z, table
+
+
 @given(n=st.integers(4, 200), f=st.integers(1, 24), d=st.integers(1, 70),
        seed=st.integers(0, 99))
 @settings(max_examples=12, deadline=None)
 def test_edge_softmax_matches_ref(n, f, d, seed):
+    """The kernel forward (rows gathered in the kernel) and the chunked
+    XLA forward against the oracle, 1 to 3 heads."""
     rng = np.random.default_rng(seed)
-    scores = jnp.asarray(rng.standard_normal((n, f)), jnp.float32)
-    mask = jnp.asarray((rng.random((n, f)) > 0.3).astype(np.float32))
-    vals = jnp.asarray(rng.standard_normal((n, f, d)), jnp.float32)
-    got = edge_softmax_aggregate(scores, mask, vals)
-    want = ref.edge_softmax_ref(scores, mask, vals)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
+    args = _attention_case(rng, n, f, d, heads=1 + seed % 3)
+    want = ref.edge_softmax_ref(*args)
+    for fused in (True, False):
+        got = edge_softmax_aggregate(*args, fused=fused)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
 
 
 def test_edge_softmax_fully_masked_rows_are_zero():
-    scores = jnp.zeros((8, 4), jnp.float32)
+    scores = jnp.zeros((8, 4, 2), jnp.float32)
     mask = jnp.zeros((8, 4), jnp.float32)
-    vals = jnp.ones((8, 4, 16), jnp.float32)
-    out = edge_softmax_aggregate(scores, mask, vals)
-    np.testing.assert_allclose(np.asarray(out), 0.0)
+    z = jnp.ones((8, 32), jnp.float32)
+    table = jnp.zeros((8, 4), jnp.int32)
+    for fused in (True, False):
+        out = edge_softmax_aggregate(scores, mask, z, table, fused=fused)
+        np.testing.assert_allclose(np.asarray(out), 0.0)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_edge_softmax_dtypes(dtype):
     rng = np.random.default_rng(1)
-    scores = jnp.asarray(rng.standard_normal((32, 8)), dtype)
-    mask = jnp.asarray((rng.random((32, 8)) > 0.5).astype(np.float32))
-    vals = jnp.asarray(rng.standard_normal((32, 8, 24)), dtype)
-    got = edge_softmax_aggregate(scores, mask, vals)
-    want = ref.edge_softmax_ref(scores, mask, vals)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-2, atol=2e-2)
+    args = _attention_case(rng, 32, 8, 12, heads=2, dtype=dtype)
+    want = ref.edge_softmax_ref(*args)
+    for fused in (True, False):
+        got = edge_softmax_aggregate(*args, fused=fused)
+        assert got.dtype == dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want), rtol=2e-2, atol=2e-2)
 
 
 # --------------------------------------------------------------------------
@@ -176,7 +189,7 @@ def test_chunked_scan_strict_matches_stepwise():
 
 
 # --------------------------------------------------------------------------
-# Fused GAT path: kernel forward + oracle-VJP backward == plain JAX exactly
+# Fused GAT path: kernel forward + chunked XLA backward == plain JAX exactly
 # --------------------------------------------------------------------------
 def test_fused_gat_layer_matches_plain_forward_and_grad():
     from repro.graph.csr import build_neighbor_table

@@ -15,7 +15,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.engine import EngineConfig, RoundProgram
 from repro.core.machine import make_loss_fn
 from repro.graph.datasets import rmat_graph
-from repro.kernels.edge_softmax import edge_softmax
+from repro.kernels.edge_softmax import block_rows, gat_attention, lane_rows
 from repro.kernels.linear_scan import linear_scan_chunked
 from repro.kernels.quantize import dequantize_rows, quantize_rows
 from repro.kernels.spmm import spmm_bcsr
@@ -73,10 +73,15 @@ def _kernel_case(name, s):
                                           block_d=128),
                 (_sds(s, (1024, 16), i32), _sds(s, (1024, 16, 8, 128)),
                  _sds(s, (8192, HIDDEN))))
-    if name == "edge_softmax":
-        return (lambda sc, m, v: edge_softmax(sc, m, v, interpret=False),
-                (_sds(s, (4096, FANOUT)), _sds(s, (4096, FANOUT)),
-                 _sds(s, (4096, FANOUT, HIDDEN))))
+    if name == "edge_softmax":             # GAT: 3 heads of 250, 16-wide
+        heads, width, sub = 3, 17, lane_rows(750)   # bucket + the self slot
+        rows = block_rows(width, sub)
+        return (lambda t, a, z: gat_attention(
+                    t, a, z, heads=heads, head_dim=250, block_rows=rows,
+                    interpret=False),
+                (_sds(s, (64 * rows, width), i32),
+                 _sds(s, (64 * rows, width * heads)),
+                 _sds(s, (NODES, sub, 128))))
     strict = name == "linear_scan_strict"    # rwkv6-1.6b: 32 heads of 64
     bh, t, d = 64, 256, 64
     return (lambda q, k, v, w, h, u: linear_scan_chunked(
