@@ -21,7 +21,8 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.gnn.model import GNNModel, cross_entropy_on_batch, f1_micro
+from repro.models.gnn.agg import row_ids
+from repro.models.gnn.model import GNNModel, cross_entropy, f1_micro
 from repro.optim.optimizers import Optimizer, apply_updates, masked_update
 
 
@@ -40,16 +41,16 @@ def make_loss_fn(model: GNNModel) -> Callable:
     per-step simulation loop, the vectorized round engine
     (:mod:`repro.core.engine`), and the shard_map runtime
     (:mod:`repro.distributed.gnn_sharded`) — so backends can be compared
-    bit-for-bit.
+    bit-for-bit.  The forward computes the batch's logits alone
+    (``rows=batch``, :meth:`~repro.models.gnn.model.GNNModel.apply`).
     """
 
     def loss_fn(params, feats, table, mask, batch, labels, bmask, agg=None):
         # ``agg`` threads optional prebuilt aggregation-layout operands
         # (repro.models.gnn.agg) into the forward — the correction phase
-        # and serving pass the edge-centric full-neighbor operands here;
-        # the sampled local rounds leave it None (padded path)
-        logits = model.apply(params, feats, table, mask, agg=agg)
-        lg = logits[batch]
+        # and serving pass the full-neighbor operands here; the sampled
+        # local rounds leave it None (padded path)
+        lg = model.apply(params, feats, table, mask, agg=agg, rows=batch)
         lb = labels[batch]
         logp = jax.nn.log_softmax(lg, axis=-1)
         nll = -jnp.take_along_axis(logp, lb[:, None], axis=-1)[:, 0]
@@ -155,13 +156,15 @@ def make_eval_fn(model: GNNModel) -> Callable:
     score' — computed on the server with the complete graph).  ``agg``
     optionally carries prebuilt aggregation operands for the full-neighbor
     table (the degree buckets of :func:`repro.models.gnn.agg.
-    bucketed_operands`); ``None`` aggregates over ``table``/``mask``."""
+    bucketed_operands`); ``None`` aggregates over ``table``/``mask``.
+    ``nodes`` are the evaluated node ids, or their own neighbour slots
+    prebuilt (:func:`repro.models.gnn.agg.degree_buckets` of the nodes);
+    the forward computes their logits alone."""
 
     @jax.jit
     def evaluate(params, feats, table, mask, labels, nodes, agg=None):
-        logits = model.apply(params, feats, table, mask, agg=agg)
-        loss = cross_entropy_on_batch(logits, labels, nodes)
-        score = f1_micro(logits, labels, nodes)
-        return loss, score
+        logits = model.apply(params, feats, table, mask, agg=agg, rows=nodes)
+        lb = labels[row_ids(nodes)]
+        return cross_entropy(logits, lb), f1_micro(logits, lb)
 
     return evaluate

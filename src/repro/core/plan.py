@@ -91,7 +91,7 @@ from repro.graph.sampling import (
 )
 from repro.models.gnn.agg import (
     LAYOUTS as AGG_LAYOUTS, build_agg_operands, bucketed_operands,
-    choose_layout, full_table_stats,
+    choose_layout, degree_buckets, full_table_stats,
 )
 from repro.models.gnn.model import GNNModel
 from repro.optim import OPTIMIZERS, Optimizer, make_optimizer
@@ -675,11 +675,26 @@ class RoundSampler:
             _device_round,
             static_argnames=("num_steps", "width", "batch_size"))
 
+    def top_rows_stats(self) -> Dict:
+        """The rows the loss reads, for which the forward computes the
+        model's last ops alone (:meth:`~repro.models.gnn.model.GNNModel.
+        apply` with ``rows``): per local step over all machines, per
+        correction step and in the evaluation; and the op where the cut
+        starts (:meth:`~repro.models.gnn.model.GNNModel.top_cut_op`), or
+        ``"none"``."""
+        cut = self.model.top_cut_op()
+        return {"top_rows_local": self.num_machines * self.batch_size,
+                "top_rows_correction": self.plan.server.server_batch_size,
+                "top_rows_eval": len(self.data.val_nodes),
+                "top_cut_op": "none" if cut is None else cut}
+
     def attention_stats(self) -> Dict:
         """GAT's engagement: slots its attention reads per round in the
-        forward passes of the local phase (P·K steps, every layer, every
-        padded local row over ``fanout`` slots) and of the server side (S
-        correction steps and the evaluation, every layer, over the degree
+        forward passes of the local phase (P·K steps; below the last layer
+        every padded local row over ``fanout`` slots, at it the batch's
+        rows) and of the server side (S correction steps and the
+        evaluation; below the last layer over the degree buckets, at it the
+        batch's rows at the widest bucket's width, or the validation rows'
         buckets), the node's own slot included with ``self_loop``; the XLA
         attention's chunk in bytes and whether the kernel runs.  Empty for
         other models."""
@@ -688,14 +703,22 @@ class RoundSampler:
             return {}
         from repro.kernels.ops import ATTENTION_CHUNK_BYTES
         own = int(model.self_loop)
-        bucket_slots = sum(t.shape[0] * (t.shape[1] + own)
-                           for t in self.full_agg.buckets.tables)
+
+        def slots(buckets):
+            return sum(t.shape[0] * (t.shape[1] + own)
+                       for t in buckets.tables)
+
+        below = model.num_layers - 1
+        full = below * slots(self.full_agg.buckets)
+        width = max(t.shape[1] for t in self.full_agg.buckets.tables) + own
+        val = degree_buckets(self.data.graph, self.data.val_nodes)
         loc, srv = self.plan.local, self.plan.server
-        return {"gat_local_slots": (self.num_machines * loc.local_k
-                                    * model.num_layers * self.n_max
+        local = below * self.n_max + self.batch_size
+        corr = full + srv.server_batch_size * width
+        return {"gat_local_slots": (self.num_machines * loc.local_k * local
                                     * (self.fanout + own)),
-                "gat_server_slots": ((srv.correction_steps + 1)
-                                     * model.num_layers * bucket_slots),
+                "gat_server_slots": (srv.correction_steps * corr + full
+                                     + slots(val)),
                 "gat_chunk_bytes": ATTENTION_CHUNK_BYTES,
                 "gat_kernel": bool(model.fused_gat)}
 
@@ -1054,9 +1077,13 @@ class RoundSampler:
         raise ValueError(f"unknown round kind {kind!r}")
 
     def evaluate(self, params, nodes):
+        """Loss and score over ``nodes``, whose own slots are built once
+        and cached on the graph (:func:`repro.models.gnn.agg.
+        degree_buckets`)."""
         loss, score = self.eval_fn(params, self.full_feats, self.full_table_j,
                                    self.full_mask_j, self.full_labels,
-                                   jnp.asarray(nodes), self.full_agg)
+                                   degree_buckets(self.data.graph, nodes),
+                                   self.full_agg)
         with span("read"):
             return float(loss), float(score)
 
@@ -1377,7 +1404,8 @@ class PlanTrainer:
                       "sampler_placement": sampler.placement,
                       "sampler_overlap": plan.sampler.resolved_overlap,
                       "corr_agg_layout": sampler.corr_agg_layout,
-                      **sampler.full_agg_stats, **sampler.attention_stats()}
+                      **sampler.full_agg_stats, **sampler.top_rows_stats(),
+                      **sampler.attention_stats()}
         if any(d.kind == "ext" for d in self.descs):
             meta.update({
                 "halo_executed": not plan.comm.host_halo,

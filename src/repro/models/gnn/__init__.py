@@ -17,7 +17,7 @@ from repro.models.gnn.model import (
     GNNModel,
     build_model,
     init_params,
-    cross_entropy_on_batch,
+    cross_entropy,
     f1_micro,
 )
 
@@ -36,6 +36,6 @@ __all__ = [
     "GNNModel",
     "build_model",
     "init_params",
-    "cross_entropy_on_batch",
+    "cross_entropy",
     "f1_micro",
 ]

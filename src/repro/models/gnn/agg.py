@@ -287,15 +287,26 @@ def bucket_widths(deg: np.ndarray, max_deg: int) -> np.ndarray:
     return np.minimum(np.where(deg <= 32, -(-deg // 8) * 8, pow2), max_deg)
 
 
-def degree_buckets(graph: CSRGraph) -> DegreeBuckets:
+def degree_buckets(graph: CSRGraph,
+                   nodes: Optional[np.ndarray] = None) -> DegreeBuckets:
     """The graph's full-neighbor table as :class:`DegreeBuckets`, built once
-    and cached on the graph."""
+    and cached on the graph.  With ``nodes``, the rows of those nodes
+    alone, in their order (repeats kept): ``order`` holds node ids and
+    ``slot_of[i]`` the concatenated row of ``nodes[i]``, so the bucketed
+    aggregations return ``(len(nodes), d)`` (:func:`row_slots`)."""
     cache = _graph_cache(graph)
-    if "buckets" not in cache:
-        deg, n = graph.degrees(), graph.num_nodes
-        width = bucket_widths(deg, max(graph.max_degree(), 1))
-        order = np.argsort(width, kind="stable")
-        widths, starts = np.unique(width[order], return_index=True)
+    key = "buckets"
+    if nodes is not None:
+        nodes = np.asarray(nodes, np.int64)
+        key = ("buckets", nodes.tobytes())
+    if key not in cache:
+        deg = graph.degrees()
+        ids = np.arange(graph.num_nodes) if nodes is None else nodes
+        n = ids.size
+        width = bucket_widths(deg[ids], max(graph.max_degree(), 1))
+        pos = np.argsort(width, kind="stable")
+        order = ids[pos]
+        widths, starts = np.unique(width[pos], return_index=True)
         tables, masks = [], []
         for w, rows in zip(widths, np.split(order, starts[1:])):
             if w == 0:
@@ -307,12 +318,61 @@ def degree_buckets(graph: CSRGraph) -> DegreeBuckets:
             tables.append(jnp.asarray(tab))
             masks.append(jnp.asarray(msk))
         slot_of = np.empty(n, np.int32)
-        slot_of[order] = np.arange(n, dtype=np.int32)
-        cache["buckets"] = DegreeBuckets(
+        slot_of[pos] = np.arange(n, dtype=np.int32)
+        cache[key] = DegreeBuckets(
             tables=tuple(tables), masks=tuple(masks),
             order=jnp.asarray(order, jnp.int32),
             slot_of=jnp.asarray(slot_of))
-    return cache["buckets"]
+    return cache[key]
+
+
+def row_ids(rows) -> Any:
+    """The node ids of output rows given as ids or as their
+    :class:`DegreeBuckets`, in output order."""
+    if not isinstance(rows, DegreeBuckets):
+        return rows
+    return rows.order if len(rows.tables) == 1 else rows.order[rows.slot_of]
+
+
+def row_slots(rows, table, mask,
+              agg: Optional[AggOperands] = None) -> Optional[DegreeBuckets]:
+    """The neighbour slots of the output rows ``rows`` as
+    :class:`DegreeBuckets` whose aggregations come out in ``rows``' order.
+
+    ``rows`` are node ids ``(R,)``, or prebuilt buckets
+    (:func:`degree_buckets` with ``nodes``), returned as they are.  For ids:
+    on the padded path one bucket of ``table[rows]``/``mask[rows]``; with
+    degree buckets in ``agg``, each row's slots gathered out of its bucket
+    on the device into one bucket as wide as the widest, the slots past the
+    row's bucket padding that points at the row itself, so the program
+    keeps one shape for any rows.  ``None`` where ``agg`` carries no buckets
+    (the ``csr`` layout): there the rows are selected after the full
+    aggregation.
+    """
+    if isinstance(rows, DegreeBuckets):
+        return rows
+    r = rows.shape[0]
+    slot_of = jnp.arange(r, dtype=jnp.int32)
+    if agg is None:
+        return DegreeBuckets((table[rows],), (mask[rows],), rows, slot_of)
+    if agg.buckets is None:
+        return None
+    b = agg.buckets
+    width = max(t.shape[1] for t in b.tables)
+    pos = b.slot_of[rows]
+    tab = jnp.broadcast_to(rows[:, None], (r, width)).astype(jnp.int32)
+    msk = jnp.zeros((r, width), jnp.float32)
+    start = 0
+    for t, m in zip(b.tables, b.masks):
+        n_b, w_b = t.shape
+        if n_b and w_b:
+            i = pos - start
+            mine = ((i >= 0) & (i < n_b))[:, None]
+            i = jnp.clip(i, 0, n_b - 1)
+            tab = tab.at[:, :w_b].set(jnp.where(mine, t[i], tab[:, :w_b]))
+            msk = msk.at[:, :w_b].set(jnp.where(mine, m[i], msk[:, :w_b]))
+        start += n_b
+    return DegreeBuckets((tab,), (msk,), rows, slot_of)
 
 
 def bucketed_operands(graph: CSRGraph) -> AggOperands:

@@ -14,7 +14,9 @@ edge-centric segment-sum, ``bcsr_kernel`` routes the mean/sym
 aggregations through the Pallas BCSR SpMM — the full-neighbor paths of the
 server-correction step and exact serving — and ``bucketed`` splits the
 full-neighbor table by degree (GAT's attention reads the buckets of both).
-``agg=None`` (the default) is the unchanged padded path.
+``agg=None`` (the default) is the unchanged padded path.  ``rows`` (the
+output rows' own slots, :func:`repro.models.gnn.agg.row_slots`) computes a
+layer for those rows alone, gathering their slots from the whole ``h``.
 """
 from __future__ import annotations
 
@@ -26,14 +28,18 @@ import jax.numpy as jnp
 from repro.models.gnn.agg import (
     AggOperands, bcsr_mean_aggregate, bcsr_sym_aggregate,
     bucketed_gat_aggregate, bucketed_mean_aggregate, bucketed_sym_aggregate,
-    csr_gat_aggregate, csr_mean_aggregate, csr_sym_aggregate, gat_aggregate,
+    DegreeBuckets, csr_gat_aggregate, csr_mean_aggregate, csr_sym_aggregate,
+    gat_aggregate, row_ids,
 )
 
 
 def mean_aggregate(h: jnp.ndarray, table: jnp.ndarray, mask: jnp.ndarray,
-                   agg: Optional[AggOperands] = None) -> jnp.ndarray:
+                   agg: Optional[AggOperands] = None,
+                   rows: Optional[DegreeBuckets] = None) -> jnp.ndarray:
     """(1/|Ñ(v)|) Σ_{j∈Ñ(v)} h_j — the paper's mean aggregation."""
     with jax.named_scope("aggregate"):
+        if rows is not None:
+            return bucketed_mean_aggregate(h, rows)
         if agg is not None:
             if agg.layout == "csr":
                 return csr_mean_aggregate(h, agg.edges)
@@ -66,9 +72,10 @@ def sym_aggregate(h: jnp.ndarray, table: jnp.ndarray, mask: jnp.ndarray,
 
 def gcn_layer(params: Dict, h: jnp.ndarray, table: jnp.ndarray,
               mask: jnp.ndarray, activation=jax.nn.relu,
-              agg: Optional[AggOperands] = None) -> jnp.ndarray:
+              agg: Optional[AggOperands] = None,
+              rows: Optional[DegreeBuckets] = None) -> jnp.ndarray:
     """Eq. 1: σ(mean_{j∈N(v)}(h_j) W)."""
-    a = mean_aggregate(h, table, mask, agg=agg)
+    a = mean_aggregate(h, table, mask, agg=agg, rows=rows)
     out = a @ params["w"]
     if "b" in params:
         out = out + params["b"]
@@ -77,9 +84,12 @@ def gcn_layer(params: Dict, h: jnp.ndarray, table: jnp.ndarray,
 
 def sage_layer(params: Dict, h: jnp.ndarray, table: jnp.ndarray,
                mask: jnp.ndarray, activation=jax.nn.relu,
-               agg: Optional[AggOperands] = None) -> jnp.ndarray:
+               agg: Optional[AggOperands] = None,
+               rows: Optional[DegreeBuckets] = None) -> jnp.ndarray:
     """Eq. 7: σ(h W1 + mean_nbr(h) W2)."""
-    a = mean_aggregate(h, table, mask, agg=agg)
+    a = mean_aggregate(h, table, mask, agg=agg, rows=rows)
+    if rows is not None:
+        h = h[row_ids(rows)]
     out = h @ params["w_self"] + a @ params["w_nbr"]
     if "b" in params:
         out = out + params["b"]
@@ -89,7 +99,8 @@ def sage_layer(params: Dict, h: jnp.ndarray, table: jnp.ndarray,
 def gat_layer(params: Dict, h: jnp.ndarray, table: jnp.ndarray,
               mask: jnp.ndarray, heads: int = 1, self_loop: bool = False,
               negative_slope: float = 0.2, fused: bool = False,
-              agg: Optional[AggOperands] = None) -> jnp.ndarray:
+              agg: Optional[AggOperands] = None,
+              rows: Optional[DegreeBuckets] = None) -> jnp.ndarray:
     """Eq. 10/11 with ``heads`` heads: ``(N, H·F)``, head k in columns
     ``k·F .. (k+1)·F``, before any bias or activation.
 
@@ -103,7 +114,9 @@ def gat_layer(params: Dict, h: jnp.ndarray, table: jnp.ndarray,
     layouts; the full-neighbor forwards), or the edge list of the ``csr``
     layout.  ``fused=True`` runs the forward's neighbour gather and sum in
     the Pallas kernel (``repro.kernels.edge_softmax``), which holds the
-    gathered rows in VMEM only.
+    gathered rows in VMEM only.  With ``rows`` the attention and ``h R``
+    run for those rows alone; ``z`` and the scores stay over every node,
+    which the rows' neighbours need.
     """
     n = h.shape[0]
     z = h @ params["w"]                                   # (N, H·F)
@@ -111,7 +124,11 @@ def gat_layer(params: Dict, h: jnp.ndarray, table: jnp.ndarray,
     s_src = jnp.sum(z3 * params["a_src"].reshape(heads, -1), axis=-1)
     s_dst = jnp.sum(z3 * params["a_dst"].reshape(heads, -1), axis=-1)
     kw = dict(negative_slope=negative_slope, self_loop=self_loop)
-    if agg is not None and agg.layout == "csr":
+    if rows is not None:
+        out = bucketed_gat_aggregate(z, s_src, s_dst, rows, fused=fused,
+                                     **kw)
+        h = h[row_ids(rows)]
+    elif agg is not None and agg.layout == "csr":
         out = csr_gat_aggregate(z, s_src, s_dst, agg.edges, **kw)
     elif agg is not None and agg.buckets is not None:
         out = bucketed_gat_aggregate(z, s_src, s_dst, agg.buckets,

@@ -6,9 +6,10 @@ Table 2's "Base Arch." column encodes models as operator strings:
 ``GAT`` and ``APPNP``, and returns a :class:`GNNModel` with ``init``/``apply``.
 
 ``apply(params, feats, table, mask) -> logits (N, C)`` computes embeddings
-for every node of the given (sub)graph; losses select the mini-batch rows.
-This matches the paper's computation pattern where each machine materializes
-its local hidden state and the sampled table decides Ñ(v).
+for every node of the given (sub)graph, as each machine materializes its
+local hidden state in the paper and the sampled table decides Ñ(v);
+``apply(..., rows=r)`` gives the logits of the rows ``r`` alone, which is
+what a loss reads (:meth:`GNNModel.top_cut_op`).
 """
 from __future__ import annotations
 
@@ -20,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.gnn import layers as L
+from repro.models.gnn.agg import row_ids, row_slots
 
 
 def _glorot(rng, shape):
@@ -159,52 +161,99 @@ class GNNModel:
                 d = d_out
         return dims
 
+    def top_cut_op(self) -> Optional[int]:
+        """The op at which ``apply(rows=...)`` starts computing the rows
+        alone: the last aggregation (a GAT stack's last layer), which
+        gathers the rows' slots from the whole ``h`` below it.  ``None``
+        where a BatchNorm follows the last aggregation (its statistics
+        need every row), and for APPNP (every propagation step mixes every
+        row): those select the rows after the last such op."""
+        if self.arch == "GAT":
+            return self.num_layers - 1
+        if self.arch == "APPNP":
+            return None
+        aggs = [i for i, op in enumerate(self.arch) if op in "GS"]
+        return aggs[-1] if aggs and aggs[-1] > self.arch.rfind("B") else None
+
     # ----------------------------------------------------------------- apply
     def apply(self, params: Dict, feats: jnp.ndarray, table: jnp.ndarray,
-              mask: jnp.ndarray, agg=None) -> jnp.ndarray:
-        """Logits for every node.  ``agg`` optionally threads prebuilt
-        :class:`repro.models.gnn.agg.AggOperands` into every aggregate op
-        (edge-centric / Pallas-kernel layouts for full-neighbor tables);
-        ``None`` is the unchanged padded-table path."""
+              mask: jnp.ndarray, agg=None, rows=None) -> jnp.ndarray:
+        """Logits for every node, or with ``rows`` for those rows alone:
+        ``apply(..., rows=r)`` is ``apply(...)[r]``.  ``agg`` optionally
+        threads prebuilt :class:`repro.models.gnn.agg.AggOperands` into
+        every aggregate op (edge-centric / Pallas-kernel layouts for
+        full-neighbor tables); ``None`` is the unchanged padded-table path.
+
+        ``rows`` are node ids ``(R,)``, or the rows' own neighbour slots
+        prebuilt as :func:`repro.models.gnn.agg.degree_buckets` of those
+        nodes.  Ops before :meth:`top_cut_op` run on every row, the
+        aggregation at it on the rows' slots (:func:`~repro.models.gnn.agg.
+        row_slots`), the ops after it on the rows alone; where the layout
+        gives no slots for rows (``csr``), the rows are selected after that
+        aggregation instead.
+        """
         if self.arch == "GAT":
-            return self._apply_gat(params, feats, table, mask, agg)
+            return self._apply_gat(params, feats, table, mask, agg, rows)
         if self.arch == "APPNP":
             h = jax.nn.relu(L.linear_layer(params["lin0"], feats))
             h = L.linear_layer(params["lin1"], h)
-            return L.appnp_propagate(h, table, mask, self.appnp_steps,
-                                     self.appnp_beta, agg=agg)
+            h = L.appnp_propagate(h, table, mask, self.appnp_steps,
+                                  self.appnp_beta, agg=agg)
+            return h if rows is None else h[row_ids(rows)]
+        cut, pick, slots = self._cut(table, mask, agg, rows)
         h = feats
         changing = [i for i, op in enumerate(self.arch) if op != "B"]
         last = changing[-1] if changing else len(self.arch) - 1
         for i, op in enumerate(self.arch):
+            if i == pick:
+                h = h[row_ids(rows)]
             name = f"{op.lower()}{i}"
             act = None if i == last else jax.nn.relu
+            at = slots if i == cut else None
             if op == "G":
                 h = L.gcn_layer(params[name], h, table, mask, activation=act,
-                                agg=agg)
+                                agg=agg, rows=at)
             elif op == "S":
                 h = L.sage_layer(params[name], h, table, mask, activation=act,
-                                 agg=agg)
+                                 agg=agg, rows=at)
             elif op == "L":
                 h = L.linear_layer(params[name], h, activation=act)
             elif op == "B":
                 h = L.batch_norm(params[name], h)
-        return h
+        return h[row_ids(rows)] if pick == len(self.arch) else h
+
+    def _cut(self, table, mask, agg, rows):
+        """``(cut, pick, slots)`` for :meth:`apply`: the op whose
+        aggregation runs on ``slots`` (the rows' slots) and the op before
+        which the rows are selected, each ``None`` where it does not
+        apply."""
+        if rows is None:
+            return None, None, None
+        cut = self.top_cut_op()
+        if cut is None:
+            return None, self.arch.rfind("B") + 1, None
+        slots = row_slots(rows, table, mask, agg)
+        return (cut, None, slots) if slots is not None else (None, cut + 1,
+                                                             None)
 
 
-    def _apply_gat(self, params, h, table, mask, agg):
+    def _apply_gat(self, params, h, table, mask, agg, rows):
         """The GAT stack: per layer :func:`~repro.models.gnn.layers.
         gat_layer`; between layers concat → BatchNorm → ReLU with
         ``batch_norm``, else concat → + b → ELU; the last layer's heads
-        averaged, + b."""
-        heads, n = self.num_heads, h.shape[0]
+        averaged, + b.  With ``rows``, the last layer on the rows alone."""
+        heads = self.num_heads
+        cut, pick, slots = self._cut(table, mask, agg, rows)
         for layer in range(self.num_layers):
             p = params[f"gat{layer}"]
             out = L.gat_layer(p, h, table, mask, heads=heads,
                               self_loop=self.self_loop, fused=self.fused_gat,
-                              agg=agg)
+                              agg=agg, rows=slots if layer == cut else None)
+            if layer + 1 == pick:
+                out = out[row_ids(rows)]
             if layer == self.num_layers - 1:
-                return out.reshape(n, heads, -1).mean(axis=1) + p["b"]
+                return (out.reshape(out.shape[0], heads, -1).mean(axis=1)
+                        + p["b"])
             if self.batch_norm:
                 h = jax.nn.relu(L.batch_norm(params[f"bn{layer}"], out))
             else:
@@ -221,13 +270,11 @@ def init_params(model: GNNModel, seed: int = 0) -> Dict:
     return model.init(seed)
 
 
-def cross_entropy_on_batch(logits: jnp.ndarray, labels: jnp.ndarray,
-                           batch_nodes: jnp.ndarray) -> jnp.ndarray:
-    """(1/B) Σ_{i∈ξ} φ(h_i^{(L)}, y_i) — Eq. 2/4's mini-batch loss."""
-    lg = logits[batch_nodes]
-    lb = labels[batch_nodes]
-    logp = jax.nn.log_softmax(lg, axis=-1)
-    return -jnp.take_along_axis(logp, lb[:, None], axis=-1).mean()
+def cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
+    """(1/B) Σ_{i∈ξ} φ(h_i^{(L)}, y_i) — Eq. 2/4's mini-batch loss, over
+    the batch's logits and labels."""
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    return -jnp.take_along_axis(logp, labels[:, None], axis=-1).mean()
 
 
 def f1_micro(logits: jnp.ndarray, labels: jnp.ndarray,
