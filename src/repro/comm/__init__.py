@@ -16,7 +16,6 @@ from repro.comm.compress import (
     compress_tree,
     decompress_features,
     decompress_tree,
-    machine_keys,
     wire_row_bytes,
 )
 
@@ -29,6 +28,5 @@ __all__ = [
     "compress_tree",
     "decompress_features",
     "decompress_tree",
-    "machine_keys",
     "wire_row_bytes",
 ]

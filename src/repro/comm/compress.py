@@ -94,23 +94,15 @@ def averaging_payload_bytes(params: Any, compression: str = "none") -> float:
 # --------------------------------------------------------------------------
 # Parameter-delta codecs (averaging rounds)
 # --------------------------------------------------------------------------
-def machine_keys(key: jnp.ndarray, num_machines: int) -> jnp.ndarray:
-    """Stacked per-machine keys — the same fold the shard backend applies
-    via ``jax.lax.axis_index``, so vmap and shard_map draw identical bits."""
-    return jax.vmap(lambda m: jax.random.fold_in(key, m))(
-        jnp.arange(num_machines, dtype=jnp.uint32))
-
-
 def compress_tree(delta: Any, compression: str,
-                  key: Optional[jnp.ndarray] = None, stacked: bool = False
+                  key: Optional[jnp.ndarray] = None
                   ) -> Tuple[Any, Optional[Any]]:
-    """Compress a parameter-delta pytree → ``(payload, scales)``.
+    """Compress ONE machine's parameter-delta pytree → ``(payload, scales)``.
 
-    ``stacked=True`` means leaves carry a leading machine axis (the vmap
-    backend) and get per-machine scales; ``key`` is then the stacked
-    per-machine key array from :func:`machine_keys`.  ``key=None`` falls
-    back to deterministic rounding.  ``scales`` is None for ``none``/
-    ``bf16``.
+    ``key`` seeds the stochastic rounding (the engine folds the machine's
+    ``jax.lax.axis_index`` into it, so both backends draw the same bits);
+    ``key=None`` falls back to deterministic rounding.  ``scales`` (one
+    per leaf) is None for ``none``/``bf16``.
     """
     if compression == "none":
         return delta, None
@@ -120,15 +112,9 @@ def compress_tree(delta: Any, compression: str,
     leaves, treedef = jax.tree_util.tree_flatten(delta)
     payloads, scales = [], []
     for i, leaf in enumerate(leaves):
-        rows = leaf.shape[0] if stacked else 1
-        flat = leaf.reshape(rows, -1)
-        if key is None:
-            u = None
-        elif stacked:
-            u = jax.vmap(lambda k: jax.random.uniform(
-                jax.random.fold_in(k, i), (flat.shape[1],)))(key)
-        else:
-            u = jax.random.uniform(jax.random.fold_in(key, i), flat.shape)
+        flat = leaf.reshape(1, -1)
+        u = (None if key is None else
+             jax.random.uniform(jax.random.fold_in(key, i), flat.shape))
         q, s = quantize_int8_rows(flat, u)
         payloads.append(q.reshape(leaf.shape))
         scales.append(s)

@@ -8,14 +8,16 @@ pluggable backend — so a round costs a single dispatch instead of P×K
 host round-trips:
 
 * ``backend="vmap"``       — simulation on any host: the machine axis is a
-  ``jax.vmap`` batch dimension, averaging is a mean over it.
+  ``jax.vmap`` batch dimension named ``"machine"``.
 * ``backend="shard_map"``  — one device per machine on a ``('machine',)``
-  mesh: the local phase runs device-local, averaging is one
-  ``jax.lax.pmean`` (byte-exactly the paper's communication).
+  mesh.
 
-Both backends execute the SAME per-machine round body
-(:func:`repro.core.machine.make_local_round`), so they agree numerically
-and are differential-tested against each other (``tests/test_engine.py``).
+Each round mode is written ONCE, as a per-machine body that talks to the
+other machines only through collectives over the ``"machine"`` axis
+(``jax.lax.pmean``, ``all_gather``, ``axis_index``); the backend only
+decides how that axis is bound, so both backends run the same code and are
+differential-tested against each other (``tests/test_engine.py``).  The
+local steps are :func:`repro.core.machine.make_local_round`.
 
 Three round modes cover every strategy in the paper:
 
@@ -32,10 +34,8 @@ Three round modes cover every strategy in the paper:
   ``jax.lax.all_gather`` over the ``('machine',)`` axis), splices the
   received halo rows into the extended feature buffer
   (:func:`repro.core.machine.halo_fill`), then does the sync-mode
-  per-step gradient averaging.  The ``vmap`` backend simulates the
-  collective with the same padded gathers, so both backends stay
-  differential-testable; ``History`` bytes for this mode come from the
-  executed collective's operand shapes
+  per-step gradient averaging.  ``History`` bytes for this mode come from
+  the executed collective's operand shapes
   (:meth:`~repro.graph.halo.HaloProgram.exchange_bytes`), not host-side
   accounting.
 
@@ -82,7 +82,7 @@ import jax.numpy as jnp
 from repro.checkpoint.manager import TraceCounter, trace_signature
 from repro.comm.compress import (check_compression, compress_features,
                                  compress_tree, decompress_features,
-                                 decompress_tree, machine_keys)
+                                 decompress_tree)
 from repro.core.machine import halo_fill, make_local_round, make_loss_fn
 from repro.core.schedules import KBucketing
 from repro.optim.optimizers import Optimizer, apply_updates, masked_update
@@ -305,334 +305,159 @@ class RoundProgram:
 
     # ----------------------------------------------------------- local phase
     def _build_round(self):
+        """Compile the round: ONE per-machine body per mode, lowered by the
+        configured backend.
+
+        Each body sees one machine's arrays (no leading P axis) and reaches
+        the other machines only through collectives over the named axis
+        ``"machine"``: ``pmean`` of parameters, gradients and losses,
+        ``all_gather`` of compressed payloads and halo send buffers,
+        ``axis_index`` for the stochastic-rounding key fold.  ``vmap`` binds
+        the axis name to the batch dimension, ``shard_map`` to the mesh.
+        """
         cfg = self.cfg
         local_round = make_local_round(self.model, self.local_opt,
                                        reset_opt=cfg.reset_local_opt)
         grad_fn = self._grad_fn
+        comp = cfg.compression if cfg.mode == "local" else "none"
+        stoch = self._comm_stochastic
+        ef = comp == "int8_ef"
+        halo_comp = cfg.halo_compression if cfg.mode == "halo" else "none"
+        tree_map = jax.tree_util.tree_map
+
+        # collectives go through the module's ``jax`` at trace time, so a
+        # patched ``jax.lax`` (the benchmark's planted faults) reaches them
+        def pmean(x):
+            return jax.lax.pmean(x, "machine")
+
+        def all_gather(x):
+            return None if x is None else jax.lax.all_gather(x, "machine")
 
         def masked_mean(losses, svalid):
             """Mean of per-step losses over REAL steps only (masked padding
             contributes 0 to the numerator and denominator)."""
-            per_step = losses.size // svalid.size  # machines sharing a step
-            return jnp.sum(losses) / jnp.clip(
-                jnp.sum(svalid) * per_step, 1.0, None)
-
-        comp = cfg.compression if cfg.mode == "local" else "none"
-        stoch = comp in ("int8", "int8_ef")
-        ef = comp == "int8_ef"
-        halo_comp = cfg.halo_compression if cfg.mode == "halo" else "none"
-
-        def _local_steps(params, opt_state, feats, labels, tables, masks,
-                         batches, bmasks, svalid):
-            """The K local steps per machine (vmap over P) — shared by the
-            plain and the compressed averaging paths."""
-            with jax.named_scope("local_steps"):
-                if cfg.reset_local_opt:
-                    # fresh per-round optimizer (Alg. 2 line 3): the carried
-                    # opt_state is a scalar placeholder, threaded through
-                    # unchanged so the round signature stays uniform
-                    run = lambda f, l, t, m, b, bm: local_round(
-                        params, None, f, l, t, m, b, bm, svalid)
-                    p_new, _, losses = jax.vmap(run)(feats, labels, tables,
-                                                     masks, batches, bmasks)
-                    o_new = opt_state
-                else:
-                    p_new, o_new, losses = jax.vmap(
-                        local_round,
-                        in_axes=(None, 0, 0, 0, 0, 0, 0, 0, None))(
-                        params, opt_state, feats, labels, tables, masks,
-                        batches, bmasks, svalid)
-            return p_new, o_new, losses
-
-        def round_local(params, opt_state, feats, labels, tables, masks,
-                        batches, bmasks, svalid):
-            """K local steps per machine (vmap over P), then averaging."""
-            p_new, o_new, losses = _local_steps(
-                params, opt_state, feats, labels, tables, masks, batches,
-                bmasks, svalid)
-            # Alg. 1/2 line 12 — THE inter-machine collective
-            with jax.named_scope("averaging"):
-                avg = jax.tree_util.tree_map(lambda x: jnp.mean(x, axis=0),
-                                             p_new)
-            return avg, o_new, masked_mean(losses, svalid)
-
-        def round_local_comp(params, opt_state, feats, labels, tables, masks,
-                             batches, bmasks, svalid, *extra):
-            """Compressed averaging: each machine quantizes its param DELTA
-            (new params − round input), the average is taken over the
-            dequantized deltas — exactly what the all_gather of compressed
-            payloads hands every machine — and with error feedback the
-            quantization error stays on the machine and is added back into
-            the next round's delta (EngineState.comm_residual)."""
-            p_new, o_new, losses = _local_steps(
-                params, opt_state, feats, labels, tables, masks, batches,
-                bmasks, svalid)
-            if ef:
-                comm_key, residual = extra
-            else:
-                comm_key = extra[0] if stoch else None
-                residual = None
-            delta = jax.tree_util.tree_map(lambda a, b: a - b, p_new, params)
-            if ef:
-                delta = jax.tree_util.tree_map(jnp.add, delta, residual)
-            keys = (machine_keys(comm_key, cfg.num_machines) if stoch
-                    else None)
-            with jax.named_scope("averaging"):
-                payload, scales = compress_tree(delta, comp, key=keys,
-                                                stacked=True)
-                deq = decompress_tree(payload, scales, comp)
-                avg = jax.tree_util.tree_map(
-                    lambda p0, d: p0 + jnp.mean(d, axis=0), params, deq)
-            outs = (avg, o_new, masked_mean(losses, svalid))
-            if ef:
-                outs += (jax.tree_util.tree_map(jnp.subtract, delta, deq),)
-            return outs
-
-        def round_sync(params, opt_state, feats, labels, tables, masks,
-                       batches, bmasks, svalid):
-            """Per-step gradient averaging across machines (GGS/sync)."""
-            xs = jax.tree_util.tree_map(lambda x: jnp.swapaxes(x, 0, 1),
-                                        (tables, masks, batches, bmasks))
-
-            def one(carry, step_xs):
-                p, o = carry
-                table, mask, batch, bmask, valid = step_xs   # each (P, …)
-                losses, grads = jax.vmap(
-                    grad_fn, in_axes=(None, 0, 0, 0, 0, 0, 0))(
-                    p, feats, table, mask, batch, labels, bmask)
-                g = jax.tree_util.tree_map(lambda x: jnp.mean(x, axis=0),
-                                           grads)
-                upd, o = masked_update(self.local_opt, g, o, p, valid)
-                return (apply_updates(p, upd), o), jnp.mean(losses) * valid
-
-            (params, opt_state), losses = jax.lax.scan(
-                one, (params, opt_state), xs + (svalid,))
-            return params, opt_state, masked_mean(losses, svalid)
-
-        def round_halo(params, opt_state, feats, labels, tables, masks,
-                       batches, bmasks, svalid, send_idx, recv_idx,
-                       dest_idx, recv_valid):
-            """GGS with the cut-node exchange executed: each step assembles
-            the all-gather buffer from every machine's owner-bucketed send
-            slots (the vmap simulation of the shard_map collective), fills
-            the halo rows, then does the sync-mode gradient averaging."""
-            xs = jax.tree_util.tree_map(lambda x: jnp.swapaxes(x, 0, 1),
-                                        (tables, masks, batches, bmasks))
-            flat_n = send_idx.shape[0] * send_idx.shape[1]
-            if halo_comp != "none":
-                # compressed exchange: the send buffer is quantized once
-                # (features are static within the round), and what every
-                # machine sees is the DEQUANTIZED gather — the same values
-                # the shard backend reconstructs after its all_gather of
-                # int8/bf16 payloads
-                send_c = jax.vmap(lambda f, si: f[si])(feats, send_idx)
-                payload, scales = compress_features(
-                    send_c.reshape(flat_n, feats.shape[-1]), halo_comp)
-                gathered_comp = decompress_features(payload, scales,
-                                                    halo_comp)
-
-            def one(carry, step_xs):
-                p, o = carry
-                table, mask, batch, bmask, valid = step_xs   # each (P, …)
-                if halo_comp == "none":
-                    # the exchange: what all_gather hands every machine
-                    send = jax.vmap(lambda f, si: f[si])(feats, send_idx)
-                    gathered = send.reshape(flat_n, feats.shape[-1])
-                else:
-                    gathered = gathered_comp
-
-                def machine_grads(f, ri, di, rv, t, m, b, lab, bm):
-                    return grad_fn(p, halo_fill(f, gathered, ri, di, rv),
-                                   t, m, b, lab, bm)
-
-                losses, grads = jax.vmap(machine_grads)(
-                    feats, recv_idx, dest_idx, recv_valid, table, mask,
-                    batch, labels, bmask)
-                g = jax.tree_util.tree_map(lambda x: jnp.mean(x, axis=0),
-                                           grads)
-                upd, o = masked_update(self.local_opt, g, o, p, valid)
-                return (apply_updates(p, upd), o), jnp.mean(losses) * valid
-
-            (params, opt_state), losses = jax.lax.scan(
-                one, (params, opt_state), xs + (svalid,))
-            return params, opt_state, masked_mean(losses, svalid)
-
-        body = {"local": round_local_comp if comp != "none" else round_local,
-                "sync": round_sync, "halo": round_halo}[cfg.mode]
-
-        if cfg.backend == "vmap":
-            self._round = self._jit_counting(body)
-            return
-
-        # shard_map backend: same per-machine body, one device per machine.
-        from jax.sharding import PartitionSpec as P
-
-        def masked_mean_1d(losses, svalid):
-            """Per-shard variant of ``masked_mean``: losses are (K,), no
-            machine axis in the denominator (pmean supplies it)."""
             return jnp.sum(losses) / jnp.clip(jnp.sum(svalid), 1.0, None)
 
-        def _shard_local_steps(params, opt_state, feats, labels, tables,
-                               masks, batches, bmasks, svalid):
-            """One machine's K local steps (leading P axis of size 1
-            stripped) — shared by the plain and compressed averaging."""
-            if cfg.reset_local_opt:
-                o = None  # local_round re-inits from the incoming params
-            else:
-                o = jax.tree_util.tree_map(lambda x: x[0], opt_state)
+        def round_local(params, opt_state, feats, labels, tables, masks,
+                        batches, bmasks, svalid, *extra):
+            """One machine's K local steps, then averaging (Alg. 1/2 line
+            12).  With a codec the machine compresses its param DELTA (new
+            params − round input), the compressed payloads are all-gathered
+            (int8/bf16 on the wire — what the byte accounting prices),
+            dequantized and averaged; with error feedback the quantization
+            error stays on the machine and is added back into the next
+            round's delta (``EngineState.comm_residual``).  ``extra`` is the
+            replicated comm key (int8 codecs), then the residual (int8_ef)."""
             with jax.named_scope("local_steps"):
-                return local_round(
-                    params, o, feats[0], labels[0], tables[0], masks[0],
-                    batches[0], bmasks[0], svalid)
-
-        def shard_local(params, opt_state, feats, labels, tables, masks,
-                        batches, bmasks, svalid):
-            """One machine's shard (leading P axis of size 1 stripped)."""
-            p_new, o_new, losses = _shard_local_steps(
-                params, opt_state, feats, labels, tables, masks, batches,
-                bmasks, svalid)
-            with jax.named_scope("averaging"):
-                p_avg = jax.lax.pmean(p_new, "machine")
-            loss = jax.lax.pmean(masked_mean_1d(losses, svalid), "machine")
+                # with reset_local_opt the carried opt_state is a scalar
+                # placeholder: the round re-inits from the incoming params
+                # (Alg. 2 line 3) and threads the placeholder through
+                p_new, o_new, losses = local_round(
+                    params, None if cfg.reset_local_opt else opt_state,
+                    feats, labels, tables, masks, batches, bmasks, svalid)
             if cfg.reset_local_opt:
-                o_new = opt_state  # scalar placeholder, unchanged
-            else:
-                o_new = jax.tree_util.tree_map(lambda x: x[None], o_new)
-            return p_avg, o_new, loss
-
-        def shard_local_comp(params, opt_state, feats, labels, tables, masks,
-                             batches, bmasks, svalid, *extra):
-            """Compressed averaging, one machine's shard: the collective is
-            an ``all_gather`` of the COMPRESSED delta payloads (int8/bf16 on
-            the wire — what the byte accounting prices), dequantized and
-            averaged locally.  Numerically identical to the vmap
-            simulation's mean over dequantized deltas."""
-            p_new, o_new, losses = _shard_local_steps(
-                params, opt_state, feats, labels, tables, masks, batches,
-                bmasks, svalid)
+                o_new = opt_state
+            loss = pmean(masked_mean(losses, svalid))
+            if comp == "none":
+                with jax.named_scope("averaging"):
+                    return pmean(p_new), o_new, loss
+            delta = tree_map(jnp.subtract, p_new, params)
             if ef:
-                comm_key, residual = extra
-                res_m = jax.tree_util.tree_map(lambda x: x[0], residual)
-            else:
-                comm_key = extra[0] if stoch else None
-                res_m = None
-            delta = jax.tree_util.tree_map(jnp.subtract, p_new, params)
-            if ef:
-                delta = jax.tree_util.tree_map(jnp.add, delta, res_m)
-            key_m = (jax.random.fold_in(comm_key,
-                                        jax.lax.axis_index("machine"))
-                     if stoch else None)
+                delta = tree_map(jnp.add, delta, extra[-1])
+            me = jax.lax.axis_index("machine")
+            key = jax.random.fold_in(extra[0], me) if stoch else None
             with jax.named_scope("averaging"):
-                payload, scales = compress_tree(delta, comp, key=key_m)
-                g_payload = jax.lax.all_gather(payload, "machine")
-                g_scales = (jax.lax.all_gather(scales, "machine")
-                            if scales is not None else None)
-                deq_all = decompress_tree(g_payload, g_scales, comp)
-                p_avg = jax.tree_util.tree_map(
-                    lambda p0, d: p0 + jnp.mean(d, axis=0), params, deq_all)
-            loss = jax.lax.pmean(masked_mean_1d(losses, svalid), "machine")
-            if cfg.reset_local_opt:
-                o_out = opt_state  # scalar placeholder, unchanged
-            else:
-                o_out = jax.tree_util.tree_map(lambda x: x[None], o_new)
-            outs = (p_avg, o_out, loss)
-            if ef:
-                deq_self = decompress_tree(payload, scales, comp)
-                res_new = jax.tree_util.tree_map(jnp.subtract, delta,
-                                                 deq_self)
-                outs += (jax.tree_util.tree_map(lambda x: x[None], res_new),)
-            return outs
+                payload, scales = compress_tree(delta, comp, key=key)
+                deq_all = decompress_tree(all_gather(payload),
+                                          all_gather(scales), comp)
+                avg = tree_map(lambda p0, d: p0 + jnp.mean(d, axis=0),
+                               params, deq_all)
+            if not ef:
+                return avg, o_new, loss
+            # the residual: what this machine meant to send less what the
+            # others received from it
+            residual = tree_map(lambda d, g: d - g[me], delta, deq_all)
+            return avg, o_new, loss, residual
 
-        def shard_sync(params, opt_state, feats, labels, tables, masks,
-                       batches, bmasks, svalid):
-            feats_p, labels_p = feats[0], labels[0]
+        def round_sync(params, opt_state, feats, labels, tables, masks,
+                       batches, bmasks, svalid, *halo):
+            """Per-step gradient averaging across machines (sync).  In halo
+            mode (GGS, ``halo`` = the machine's send/recv/dest/valid index
+            rows) every step first all-gathers the owner-bucketed send
+            buffers — a fixed-shape collective — and splices the received
+            rows into the machine's feature rows; masked steps still
+            exchange, so the program stays shape-stable under K-bucketing.
+            """
+            if halo:
+                send_idx, recv_idx, dest_idx, recv_valid = halo
+                # quantized once per round (features are static within it);
+                # the per-step collective moves the compressed payloads
+                payload, scales = compress_features(feats[send_idx],
+                                                    halo_comp)
 
-            def one(carry, step_xs):
+            def one(carry, xs):
                 p, o = carry
-                table, mask, batch, bmask, valid = step_xs
-                loss, grads = grad_fn(p, feats_p, table, mask, batch,
-                                      labels_p, bmask)
-                grads = jax.lax.pmean(grads, "machine")
-                upd, o = masked_update(self.local_opt, grads, o, p, valid)
-                return (apply_updates(p, upd), o), jax.lax.pmean(
-                    loss, "machine") * valid
-
-            (params, opt_state), losses = jax.lax.scan(
-                one, (params, opt_state), (tables[0], masks[0], batches[0],
-                                           bmasks[0], svalid))
-            return params, opt_state, masked_mean_1d(losses, svalid)
-
-        def shard_halo(params, opt_state, feats, labels, tables, masks,
-                       batches, bmasks, svalid, send_idx, recv_idx,
-                       dest_idx, recv_valid):
-            """One machine's shard of the halo round: a REAL fixed-shape
-            ``all_gather`` of the owner-bucketed send buffer each scan step,
-            then the sync-mode per-step gradient pmean.  Masked steps
-            (``svalid == 0``) skip the optimizer but still execute the
-            exchange, so the program stays shape-stable under K-bucketing."""
-            feats_p, labels_p = feats[0], labels[0]
-            send_i, recv_i = send_idx[0], recv_idx[0]
-            dest_i, rvalid = dest_idx[0], recv_valid[0]
-            if halo_comp != "none":
-                # quantize the send buffer once per round (features are
-                # static); the per-step collective then moves int8/bf16
-                # payloads — the compressed wire format the accounting and
-                # the dryrun HLO cross-check price
-                send_payload, send_scales = compress_features(
-                    feats_p[send_i], halo_comp)
-
-            def one(carry, step_xs):
-                p, o = carry
-                table, mask, batch, bmask, valid = step_xs
-                if halo_comp == "none":
-                    gathered = jax.lax.all_gather(feats_p[send_i], "machine")
-                    gflat = gathered.reshape(-1, feats_p.shape[-1])
-                else:
-                    g_p = jax.lax.all_gather(send_payload, "machine")
-                    g_s = (jax.lax.all_gather(send_scales, "machine")
-                           if send_scales is not None else None)
-                    gflat = decompress_features(g_p, g_s, halo_comp).reshape(
-                        -1, feats_p.shape[-1])
-                ext = halo_fill(feats_p, gflat, recv_i, dest_i, rvalid)
-                loss, grads = grad_fn(p, ext, table, mask, batch, labels_p,
+                table, mask, batch, bmask, valid = xs
+                f = feats
+                if halo:
+                    gathered = decompress_features(
+                        all_gather(payload), all_gather(scales), halo_comp)
+                    f = halo_fill(feats,
+                                  gathered.reshape(-1, feats.shape[-1]),
+                                  recv_idx, dest_idx, recv_valid)
+                loss, grads = grad_fn(p, f, table, mask, batch, labels,
                                       bmask)
-                grads = jax.lax.pmean(grads, "machine")
-                upd, o = masked_update(self.local_opt, grads, o, p, valid)
-                return (apply_updates(p, upd), o), jax.lax.pmean(
-                    loss, "machine") * valid
+                upd, o = masked_update(self.local_opt, pmean(grads), o, p,
+                                       valid)
+                return (apply_updates(p, upd), o), pmean(loss) * valid
 
             (params, opt_state), losses = jax.lax.scan(
-                one, (params, opt_state), (tables[0], masks[0], batches[0],
-                                           bmasks[0], svalid))
-            return params, opt_state, masked_mean_1d(losses, svalid)
+                one, (params, opt_state),
+                (tables, masks, batches, bmasks, svalid))
+            return params, opt_state, masked_mean(losses, svalid)
 
-        pspec = P("machine")
-        if cfg.mode == "local":
-            ospec = P() if cfg.reset_local_opt else pspec
-            in_specs = (P(), ospec, pspec, pspec, pspec, pspec, pspec, pspec,
-                        P())
-            out_specs = (P(), ospec, P())
-            shard_body = shard_local
-            if comp != "none":
-                shard_body = shard_local_comp
-                if stoch:
-                    in_specs += (P(),)        # replicated comm key
-                if ef:
-                    in_specs += (pspec,)      # per-machine EF residual
-                    out_specs += (pspec,)
-        elif cfg.mode == "halo":
-            in_specs = (P(), P(), pspec, pspec, pspec, pspec, pspec, pspec,
-                        P(), pspec, pspec, pspec, pspec)
-            out_specs = (P(), P(), P())
-            shard_body = shard_halo
+        # the one statement of which arguments and outputs hold a slice per
+        # machine (True) and which are replicated (False): params, opt
+        # state, feats, labels, tables, masks, batches, bmasks, svalid, then
+        # the halo index rows / comm key / EF residual
+        opt_m = cfg.mode == "local" and not cfg.reset_local_opt
+        in_m = (False, opt_m) + (True,) * 6 + (False,)
+        out_m = (False, opt_m, False)
+        if cfg.mode == "halo":
+            in_m += (True,) * 4
+        if stoch:
+            in_m += (False,)
+        if ef:
+            in_m += (True,)
+            out_m += (True,)
+        body = round_local if cfg.mode == "local" else round_sync
+
+        if cfg.backend == "vmap":
+            mapped = jax.vmap(body, axis_name="machine", in_axes=tuple(
+                0 if m else None for m in in_m))
+
+            def run(*args):
+                # replicated outputs leave the vmap on axis 0 and are read
+                # at machine 0: with out_axes=None, vmap would raise instead
+                # of returning machine 0's value if a collective were left
+                # out (bench/faults.py's exchange_left_out)
+                return tuple(o if m else tree_map(lambda x: x[0], o)
+                             for o, m in zip(mapped(*args), out_m))
         else:
-            in_specs = (P(), P(), pspec, pspec, pspec, pspec, pspec, pspec,
-                        P())
-            out_specs = (P(), P(), P())
-            shard_body = shard_sync
-        self._round = self._jit_counting(jax.shard_map(
-            shard_body, mesh=self.mesh, in_specs=in_specs,
-            out_specs=out_specs, check_vma=False))
+            from jax.sharding import PartitionSpec as P
+
+            def per_shard(*args):
+                # strip / restore the size-1 shard of per-machine arrays
+                outs = body(*(tree_map(lambda x: x[0], a) if m else a
+                              for a, m in zip(args, in_m)))
+                return tuple(tree_map(lambda x: x[None], o) if m else o
+                             for o, m in zip(outs, out_m))
+
+            specs = [tuple(P("machine") if m else P() for m in ms)
+                     for ms in (in_m, out_m)]
+            run = jax.shard_map(per_shard, mesh=self.mesh, in_specs=specs[0],
+                                out_specs=specs[1], check_vma=False)
+        self._round = self._jit_counting(run)
 
     # ------------------------------------------------------ correction phase
     def _build_correction(self):

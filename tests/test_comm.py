@@ -33,7 +33,7 @@ import pytest
 from repro.comm.compress import (
     COMPRESSIONS, HALO_COMPRESSIONS, averaging_payload_bytes,
     check_compression, compress_features, compress_tree,
-    decompress_features, decompress_tree, machine_keys, wire_row_bytes,
+    decompress_features, decompress_tree, wire_row_bytes,
 )
 from repro.core import DistConfig, build_trainer
 from repro.core.plan import (
@@ -131,12 +131,18 @@ def test_compress_tree_roundtrip_and_pricing():
             tol = 0.0 if comp == "none" else 0.05
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=tol)
-    # stacked (vmap) form: per-machine rows, per-machine scales
+    # per machine under vmap, keys folded from axis_index as the engine
+    # does: per-machine payloads and scales
     stacked = jax.tree_util.tree_map(
         lambda x: jnp.stack([x, 2 * x]), tree)
-    keys = machine_keys(jax.random.PRNGKey(0), 2)
-    payload, scales = compress_tree(stacked, "int8", key=keys,
-                                    stacked=True)
+
+    def one_machine(delta):
+        key = jax.random.fold_in(jax.random.PRNGKey(0),
+                                 jax.lax.axis_index("machine"))
+        return compress_tree(delta, "int8", key=key)
+
+    payload, scales = jax.vmap(one_machine, axis_name="machine")(stacked)
+    assert all(s.shape[0] == 2 for s in jax.tree_util.tree_leaves(scales))
     out = decompress_tree(payload, scales, "int8")
     for a, b in zip(jax.tree_util.tree_leaves(out),
                     jax.tree_util.tree_leaves(stacked)):
@@ -340,8 +346,8 @@ def test_serving_halo_compression(tiny):
 @pytest.mark.slow
 def test_backends_agree_compressed():
     """vmap and shard_map must draw IDENTICAL stochastic-rounding bits
-    (machine_keys vs axis_index fold) — params agree bit-exactly for every
-    codec, including int8_ef's residual."""
+    (both fold the machine's axis_index into the key) — params agree
+    bit-exactly for every codec, and so does int8_ef's residual."""
     code = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
@@ -386,9 +392,8 @@ for comp in ("bf16", "int8", "int8_ef"):
             for k in progs:
                 states[k], _ = progs[k].run_round(states[k], ctx.feats_j,
                                                   ctx.labels_j, inputs)
-            for a, b in zip(
-                    jax.tree_util.tree_leaves(states["vmap"].params),
-                    jax.tree_util.tree_leaves(states["shard_map"].params)):
+            pair = [(s.params, s.comm_residual) for s in states.values()]
+            for a, b in zip(*(jax.tree_util.tree_leaves(t) for t in pair)):
                 max_diff = max(max_diff, float(jnp.abs(a - b).max()))
     out[comp] = max_diff
 print(json.dumps(out))

@@ -140,9 +140,14 @@ def test_llcg_byte_accounting_is_per_round(tiny):
 
 
 @pytest.mark.slow
-def test_vmap_and_shard_map_backends_agree():
-    """Both backends, same round inputs ⇒ same params (subprocess: needs
-    a forced multi-device host before jax init)."""
+@pytest.mark.parametrize("mode,reset,corr", [
+    ("local", True, True),     # LLCG: fresh local optimizer each round
+    ("local", False, True),    # persistent, stacked (P, …) local optimizer
+    ("sync", True, False),     # per-step gradient pmean, shared optimizer
+], ids=["local", "local-persistent-opt", "sync"])
+def test_vmap_and_shard_map_backends_agree(mode, reset, corr):
+    """Both backends, same round inputs ⇒ same params and optimizer state
+    (subprocess: needs a forced multi-device host before jax init)."""
     code = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
@@ -157,6 +162,7 @@ from repro.data.graph_loader import sample_round
 from repro.graph import sbm_graph
 from repro.models.gnn import build_model
 
+MODE, RESET, CORR = %r, %r, %r
 data = sbm_graph(num_nodes=120, num_classes=3, feature_dim=8,
                  feature_snr=0.4, homophily=0.9, seed=0)
 model = build_model("GG", data.feature_dim, data.num_classes, hidden_dim=16)
@@ -165,13 +171,14 @@ cfg = DistConfig(num_machines=2, rounds=2, local_k=3, batch_size=8,
                  partition_method="random", seed=0)
 ctx = _Context(data, model, cfg)
 mesh = Mesh(np.asarray(jax.devices()[:2]), ("machine",))
+ecfg = dict(num_machines=2, mode=MODE, with_correction=CORR,
+            reset_local_opt=RESET)
+server = ctx.server_opt if CORR else None
 progs = {
-    "vmap": RoundProgram(model, ctx.opt, ctx.server_opt,
-        EngineConfig(num_machines=2, mode="local", backend="vmap",
-                     with_correction=True)),
-    "shard_map": RoundProgram(model, ctx.opt, ctx.server_opt,
-        EngineConfig(num_machines=2, mode="local", backend="shard_map",
-                     with_correction=True), mesh=mesh),
+    "vmap": RoundProgram(model, ctx.opt, server,
+        EngineConfig(backend="vmap", **ecfg)),
+    "shard_map": RoundProgram(model, ctx.opt, server,
+        EngineConfig(backend="shard_map", **ecfg), mesh=mesh),
 }
 params0 = model.init(cfg.seed)
 states = {k: p.init_state(params0) for k, p in progs.items()}
@@ -181,15 +188,18 @@ with mesh:
         arrs = sample_round(ctx.loaders, cfg.local_k, cfg.batch_size,
                             ctx.n_max, ctx.fanout, ctx.rng)
         inputs = RoundInputs(*(jnp.asarray(a) for a in arrs),
-                             **ctx.sample_correction())
+                             **(ctx.sample_correction() if CORR else {}))
         for k in progs:
             states[k], _ = progs[k].run_round(states[k], ctx.feats_j,
                                               ctx.labels_j, inputs)
-        for a, b in zip(jax.tree_util.tree_leaves(states["vmap"].params),
-                        jax.tree_util.tree_leaves(states["shard_map"].params)):
+        pair = [(s.params, s.local_opt_state) for s in states.values()]
+        for a, b in zip(*(jax.tree_util.tree_leaves(t) for t in pair)):
+            assert a.shape == b.shape, (a.shape, b.shape)
             max_diff = max(max_diff, float(jnp.abs(a - b).max()))
-print(json.dumps({"max_diff": max_diff}))
-"""
+opt = jax.tree_util.tree_leaves(states["shard_map"].local_opt_state)
+print(json.dumps({"max_diff": max_diff,
+                  "opt_shapes": [list(x.shape) for x in opt]}))
+""" % (mode, reset, corr)
     env = dict(os.environ, PYTHONPATH=SRC)
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run([sys.executable, "-c", code], env=env,
@@ -197,3 +207,6 @@ print(json.dumps({"max_diff": max_diff}))
     assert proc.returncode == 0, proc.stderr[-2000:]
     out = json.loads(proc.stdout.strip().splitlines()[-1])
     assert out["max_diff"] < 1e-4, out
+    if mode == "local" and not reset:
+        # the persistent local optimizer stays stacked per machine
+        assert all(s[0] == 2 for s in out["opt_shapes"]), out
