@@ -726,6 +726,17 @@ class RoundSampler:
     def num_sampler_retraces(self) -> int:
         return self._sampler_traces.count_value
 
+    def device_sampler_stats(self) -> Dict:
+        """How the device sampler reads the sampled neighbor ids, for each
+        round kind whose :class:`DeviceCSR` is built: ``sampler_select``
+        (``"row_table"`` or ``"row_gather"``, chosen by the graph's max
+        degree) and ``sampler_row_table_bytes``, the row tables' total;
+        ``sampler_select`` is empty under host placement."""
+        built = self._device_csrs
+        return {"sampler_select": {k: d.select for k, d in built.items()},
+                "sampler_row_table_bytes": sum(int(d.nbr.nbytes)
+                                               for d in built.values())}
+
     def _per_machine(self, x) -> jnp.ndarray:
         """``x`` (leading machine axis) on the device.  With a mesh, one
         machine's slice per device of its ``('machine',)`` axis, so no
@@ -1403,6 +1414,7 @@ class PlanTrainer:
                       "plan": plan.describe(),
                       "sampler_placement": sampler.placement,
                       "sampler_overlap": plan.sampler.resolved_overlap,
+                      **sampler.device_sampler_stats(),
                       "corr_agg_layout": sampler.corr_agg_layout,
                       **sampler.full_agg_stats, **sampler.top_rows_stats(),
                       **sampler.attention_stats()}

@@ -26,7 +26,7 @@ and nodes with degree ≤ fanout keep all neighbors (σ²_bias → 0 in the
 full-neighbor limit).
 
 **Device-resident sampling.**  A third path moves the whole round draw onto
-the accelerator: :func:`build_device_csr` stacks P padded CSR shards into a
+the accelerator: :func:`build_device_csr` stacks P CSR shards' neighbor rows into a
 :class:`DeviceCSR` once, and :func:`sample_round_device` /
 :func:`sample_serving_tables_device` produce the same fixed-shape
 ``(P, K, n_pad, fanout)`` tables as the host paths from ``jax.random``
@@ -48,7 +48,11 @@ padded length reproduces the unbucketed stream bit-for-bit on the real
 prefix.  Neighbor subsets are uniform without replacement via the same
 random-keys ranking as the host path (threefry bits ranked per row with an
 index tie-break, implemented as a pairwise-rank compaction that avoids
-XLA's slow ``top_k`` on small widths).
+XLA's slow ``top_k`` on small widths).  The neighbor ids sit in a per-row
+table; on that path the same one-hot compaction picks the sampled ids out
+of it, so no gather runs per sampled slot.  Graphs wider than
+``_RANK_SELECT_MAX_WIDTH`` rank with ``top_k`` and gather the selected
+slots out of the row table.
 """
 from __future__ import annotations
 
@@ -342,7 +346,8 @@ _RANK_SELECT_MAX_WIDTH = 128
 
 @dataclasses.dataclass(frozen=True)
 class DeviceCSR:
-    """P padded CSR shards + train pools, resident on the accelerator.
+    """P CSR shards as padded per-row neighbor tables + train pools,
+    resident on the accelerator.
 
     One instance is built per ``(round kind, fanout)`` by
     :func:`build_device_csr` and reused every round — the device-side
@@ -351,8 +356,8 @@ class DeviceCSR:
     shard it over a ``('machine',)`` mesh).
     """
 
-    indices: Any        # (P, e_pad) int32 — CSR indices, zero-padded
-    starts: Any         # (P, n_pad) int32 — per-row neighbor-span starts
+    nbr: Any            # (P, n_pad, dmax) int32 — row's neighbors, 0 past
+    #                     its degree
     degrees: Any        # (P, n_pad) int32 — 0 on padded rows
     train_nodes: Any    # (P, t_pad) int32 — per-machine train pools
     train_counts: Any   # (P,) int32
@@ -361,19 +366,27 @@ class DeviceCSR:
 
     @property
     def num_machines(self) -> int:
-        return int(self.starts.shape[0])
+        return int(self.degrees.shape[0])
 
     @property
     def n_pad(self) -> int:
-        return int(self.starts.shape[1])
+        return int(self.degrees.shape[1])
+
+    @property
+    def select(self) -> str:
+        """How the sampled ids are read out of ``nbr``: ``"row_table"``
+        (picked by the pairwise-rank one-hot, no gather) or ``"row_gather"``
+        (``top_k`` slots gathered, ``dmax > _RANK_SELECT_MAX_WIDTH``)."""
+        return ("row_table" if self.dmax <= _RANK_SELECT_MAX_WIDTH
+                else "row_gather")
 
 
 # a pytree (dmax is static metadata), so a DeviceCSR passes straight
 # through jit/vmap boundaries
 jax.tree_util.register_dataclass(
     DeviceCSR,
-    data_fields=["indices", "starts", "degrees", "train_nodes",
-                 "train_counts", "fanouts"],
+    data_fields=["nbr", "degrees", "train_nodes", "train_counts",
+                 "fanouts"],
     meta_fields=["dmax"])
 
 
@@ -394,15 +407,13 @@ def build_device_csr(graphs: Sequence[CSRGraph], n_pad: Optional[int] = None,
     if P == 0:
         raise ValueError("build_device_csr needs at least one graph")
     n_pad = max(g.num_nodes for g in graphs) if n_pad is None else int(n_pad)
-    e_pad = max(max(g.num_edges for g in graphs), 1)
     pools = ([np.zeros(0, np.int64)] * P if train_nodes is None
              else [np.asarray(t) for t in train_nodes])
     t_pad = max(max(p.size for p in pools), int(t_pad_min), 1)
     dmax = max(max(g.max_degree() for g in graphs), 1)
     fo = ([dmax] * P if fanouts is None else [int(f) for f in fanouts])
 
-    indices = np.zeros((P, e_pad), np.int32)
-    starts = np.zeros((P, n_pad), np.int32)
+    nbr = np.zeros((P, n_pad, dmax), np.int32)
     degrees = np.zeros((P, n_pad), np.int32)
     tn = np.zeros((P, t_pad), np.int32)
     tc = np.zeros((P,), np.int32)
@@ -410,18 +421,55 @@ def build_device_csr(graphs: Sequence[CSRGraph], n_pad: Optional[int] = None,
         if g.num_nodes > n_pad:
             raise ValueError(f"graph {p} has {g.num_nodes} rows > n_pad "
                              f"{n_pad}")
-        indices[p, : g.num_edges] = g.indices
-        starts[p, : g.num_nodes] = g.indptr[:-1]
-        degrees[p, : g.num_nodes] = np.diff(g.indptr)
+        deg = np.diff(g.indptr)
+        degrees[p, : g.num_nodes] = deg
+        # edge e of row v sits in slot e - indptr[v]
+        rows = np.repeat(np.arange(g.num_nodes), deg)
+        slots = np.arange(g.num_edges) - np.repeat(g.indptr[:-1], deg)
+        nbr[p, rows, slots] = g.indices
         tn[p, : pools[p].size] = pools[p]
         tc[p] = pools[p].size
 
     put = ((lambda x: jax.device_put(jnp.asarray(x), sharding))
            if sharding is not None else jnp.asarray)
-    return DeviceCSR(indices=put(indices), starts=put(starts),
-                     degrees=put(degrees), train_nodes=put(tn),
+    return DeviceCSR(nbr=put(nbr), degrees=put(degrees), train_nodes=put(tn),
                      train_counts=put(tc),
                      fanouts=put(np.asarray(fo, np.int32)), dmax=dmax)
+
+
+def _rank_hits(bits, valid, width: int):
+    """One-hot ``(…, dmax, min(width, dmax))``: slot ``s`` holds the
+    ``j``-th smallest key of its row — the pairwise-rank path of
+    :func:`_rank_select` (``dmax <= _RANK_SELECT_MAX_WIDTH``)."""
+    dmax = bits.shape[-1]
+    w = min(width, dmax)
+    # pack the slot index into the low bits: one `>` compare then gives
+    # a strict total order (random key bits break first, index second),
+    # and invalid slots get the top bit so valid < invalid always
+    ib = max(int(dmax - 1).bit_length(), 1)
+    ia = jnp.arange(dmax, dtype=jnp.uint32)
+    keys = jnp.where(
+        valid,
+        ((bits >> jnp.uint32(1 + ib)) << jnp.uint32(ib)) | ia,
+        (jnp.uint32(1) << jnp.uint32(31)) | ia)
+    gt = keys[..., :, None] > keys[..., None, :]
+    rank = jnp.sum(gt, axis=-1, dtype=jnp.int32)                # (…, dmax)
+    slot = jnp.where(rank < w, rank, w)
+    return slot[..., :, None] == jnp.arange(w, dtype=jnp.int32)
+
+
+def _pick(hit, values, width: int):
+    """``values (…, dmax)`` at the slots ``hit`` selects, ``(…, width)``,
+    zero past ``min(width, dmax)``: a masked sum, no gather."""
+    return _pad_to(jnp.sum(jnp.where(hit, values[..., :, None], 0),
+                           axis=-2), width)
+
+
+def _pad_to(x, width: int):
+    if x.shape[-1] == width:
+        return x
+    pad = jnp.zeros(x.shape[:-1] + (width - x.shape[-1],), x.dtype)
+    return jnp.concatenate([x, pad], axis=-1)
 
 
 def _rank_select(bits, valid, width: int):
@@ -436,50 +484,33 @@ def _rank_select(bits, valid, width: int):
     because XLA's ``top_k``/``sort`` are far slower on CPU at these widths.
     """
     dmax = bits.shape[-1]
-    w = min(width, dmax)
     if dmax <= _RANK_SELECT_MAX_WIDTH:
-        # pack the slot index into the low bits: one `>` compare then gives
-        # a strict total order (random key bits break first, index second),
-        # and invalid slots get the top bit so valid < invalid always
-        ib = max(int(dmax - 1).bit_length(), 1)
-        ia = jnp.arange(dmax, dtype=jnp.uint32)
-        keys = jnp.where(
-            valid,
-            ((bits >> jnp.uint32(1 + ib)) << jnp.uint32(ib)) | ia,
-            (jnp.uint32(1) << jnp.uint32(31)) | ia)
-        gt = keys[..., :, None] > keys[..., None, :]
-        rank = jnp.sum(gt, axis=-1, dtype=jnp.int32)            # (…, dmax)
-        slot = jnp.where(rank < w, rank, w)
-        hit = slot[..., :, None] == jnp.arange(w, dtype=jnp.int32)
-        sel = jnp.sum(jnp.where(hit, ia.astype(jnp.int32)[:, None], 0),
-                      axis=-2)                                  # (…, w)
-    else:
-        keys = jnp.where(valid, bits >> jnp.uint32(1),
-                         jnp.uint32(0xFFFFFFFF))
-        # top_k takes the LARGEST, so rank complemented keys; XLA's top_k is
-        # stable, which reproduces the same lowest-index tie-break
-        _, sel = jax.lax.top_k(keys ^ jnp.uint32(0xFFFFFFFF), w)
-        sel = sel.astype(jnp.int32)
-    if w < width:
-        pad = jnp.zeros(sel.shape[:-1] + (width - w,), jnp.int32)
-        sel = jnp.concatenate([sel, pad], axis=-1)
-    return sel
+        return _pick(_rank_hits(bits, valid, width),
+                     jnp.arange(dmax, dtype=jnp.int32), width)
+    keys = jnp.where(valid, bits >> jnp.uint32(1), jnp.uint32(0xFFFFFFFF))
+    # top_k takes the LARGEST, so rank complemented keys; XLA's top_k is
+    # stable, which reproduces the same lowest-index tie-break
+    _, sel = jax.lax.top_k(keys ^ jnp.uint32(0xFFFFFFFF), min(width, dmax))
+    return _pad_to(sel.astype(jnp.int32), width)
 
 
-def _neighbor_tables_step(step_key, indices_p, starts_p, degrees_p,
-                          fanout_p, width: int, dmax: int):
+def _neighbor_tables_step(step_key, nbr_p, degrees_p, fanout_p, width: int,
+                          dmax: int):
     """One machine-step's ``(n_pad, width)`` table + mask (pure jax)."""
-    n_pad = starts_p.shape[0]
-    e_pad = indices_p.shape[0]
+    n_pad = degrees_p.shape[0]
     bits = jax.random.bits(jax.random.fold_in(step_key, 0), (n_pad, dmax),
                            dtype=jnp.uint32)
     col = jnp.arange(dmax, dtype=jnp.int32)
     valid_key = col[None, :] < degrees_p[:, None]
-    sel = _rank_select(bits, valid_key, width)                  # (n_pad, width)
     eff = jnp.minimum(degrees_p, fanout_p)
     valid = jnp.arange(width, dtype=jnp.int32)[None, :] < eff[:, None]
-    gat = jnp.clip(starts_p[:, None] + sel, 0, e_pad - 1)
-    table = jnp.where(valid, indices_p[gat], 0).astype(jnp.int32)
+    if dmax <= _RANK_SELECT_MAX_WIDTH:
+        # the one-hot that ranks the slots picks their ids too: no gather
+        picked = _pick(_rank_hits(bits, valid_key, width), nbr_p, width)
+    else:
+        picked = jnp.take_along_axis(
+            nbr_p, _rank_select(bits, valid_key, width), axis=-1)
+    table = jnp.where(valid, picked, 0).astype(jnp.int32)
     return table, valid.astype(jnp.float32)
 
 
@@ -512,14 +543,13 @@ def sample_round_device(dcsr: DeviceCSR, key, num_steps: int, width: int,
     """
     dmax = dcsr.dmax
 
-    def one_machine(p, indices_p, starts_p, degrees_p, train_p, count_p,
-                    fanout_p):
+    def one_machine(p, nbr_p, degrees_p, train_p, count_p, fanout_p):
         kp = jax.random.fold_in(key, p)
 
         def one_step(s):
             ks = jax.random.fold_in(kp, s)
             table, mask = _neighbor_tables_step(
-                ks, indices_p, starts_p, degrees_p, fanout_p, width, dmax)
+                ks, nbr_p, degrees_p, fanout_p, width, dmax)
             batch = _minibatch_step(ks, train_p, count_p, batch_size)
             return table, mask, batch
 
@@ -527,8 +557,8 @@ def sample_round_device(dcsr: DeviceCSR, key, num_steps: int, width: int,
 
     P = dcsr.num_machines
     tables, masks, batches = jax.vmap(one_machine)(
-        jnp.arange(P), dcsr.indices, dcsr.starts, dcsr.degrees,
-        dcsr.train_nodes, dcsr.train_counts, dcsr.fanouts)
+        jnp.arange(P), dcsr.nbr, dcsr.degrees, dcsr.train_nodes,
+        dcsr.train_counts, dcsr.fanouts)
     bmasks = jnp.ones((P, num_steps, batch_size), jnp.float32)
     return tables, masks, batches, bmasks
 
@@ -539,10 +569,10 @@ def sample_serving_tables_device(dcsr: DeviceCSR, key, width: int):
     per machine (step index 0) — no host loop between serving waves."""
     dmax = dcsr.dmax
 
-    def one_machine(p, indices_p, starts_p, degrees_p):
+    def one_machine(p, nbr_p, degrees_p):
         ks = jax.random.fold_in(jax.random.fold_in(key, p), 0)
-        return _neighbor_tables_step(ks, indices_p, starts_p, degrees_p,
-                                     jnp.int32(width), width, dmax)
+        return _neighbor_tables_step(ks, nbr_p, degrees_p, jnp.int32(width),
+                                     width, dmax)
 
-    return jax.vmap(one_machine)(jnp.arange(dcsr.num_machines), dcsr.indices,
-                                 dcsr.starts, dcsr.degrees)
+    return jax.vmap(one_machine)(jnp.arange(dcsr.num_machines), dcsr.nbr,
+                                 dcsr.degrees)

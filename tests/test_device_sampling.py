@@ -16,6 +16,9 @@
    plan prewarm caches every (graph, fanout) sampling plan before round 1.
 4. Serving: device tables at full width reproduce the host path's exact
    full-neighbor predictions and replay deterministically per wave content.
+5. The row-table select: tables, masks and batches equal, bit for bit, a
+   reference that gathers the selected slots out of the CSR indices, on the
+   pairwise-rank path (dmax <= 128) and on the top_k path (dmax > 128).
 """
 import dataclasses
 
@@ -30,6 +33,7 @@ from repro.core import (
     correction, halo_exchange, local_steps, lower_plan,
 )
 from repro.graph import build_device_csr, sample_round_device, sbm_graph
+from repro.graph.csr import CSRGraph
 from repro.graph.sampling import sample_serving_tables_device
 from repro.models.gnn import build_model
 from repro.serving import GNNRequest, GNNServingEngine
@@ -216,6 +220,27 @@ def test_device_placement_runs_hybrid_halo_plan(tiny):
     assert len(hist.val_score) == 4
     assert all(np.isfinite(v) for v in hist.meta["local_loss"])
     assert hist.meta["sampler_placement"] == "device"
+    assert hist.meta["sampler_select"] == {"ext": "row_table",
+                                           "local": "row_table"}
+
+
+def test_sampler_stats_report_the_select_per_kind(tiny):
+    """Each kind's DeviceCSR reports how its ids are read; host placement
+    builds none."""
+    data, model = tiny
+    kinds = {"local", "full"}
+    dev = RoundSampler(data, model, _plan("device"))
+    dev.prewarm(kinds)
+    stats = dev.device_sampler_stats()
+    assert stats["sampler_select"] == {"local": "row_table",
+                                       "full": "row_table"}
+    nbr = [dev._device_csr(k).nbr for k in sorted(kinds)]
+    assert stats["sampler_row_table_bytes"] == sum(x.nbytes for x in nbr)
+    assert nbr[0].shape == (1, data.num_nodes, data.graph.max_degree())
+    host = RoundSampler(data, model, _plan("host"))
+    host.prewarm(kinds)
+    assert host.device_sampler_stats() == {"sampler_select": {},
+                                           "sampler_row_table_bytes": 0}
 
 
 # --------------------------------------------------------------------------
@@ -255,3 +280,172 @@ def test_serving_device_tables_full_width_are_exact(tiny):
         w = int(masks[0, v].sum())
         assert set(tables[0, v, :w].tolist()) == \
             set(data.graph.neighbors(v).tolist())
+
+
+# --------------------------------------------------------------------------
+# 4. the row-table select against the CSR gather
+# --------------------------------------------------------------------------
+def _ref_rank_select(bits, valid, width):
+    """The selection as drawn before the row table: slot indices."""
+    dmax = bits.shape[-1]
+    w = min(width, dmax)
+    if dmax <= 128:
+        ib = max(int(dmax - 1).bit_length(), 1)
+        ia = jnp.arange(dmax, dtype=jnp.uint32)
+        keys = jnp.where(
+            valid,
+            ((bits >> jnp.uint32(1 + ib)) << jnp.uint32(ib)) | ia,
+            (jnp.uint32(1) << jnp.uint32(31)) | ia)
+        gt = keys[..., :, None] > keys[..., None, :]
+        rank = jnp.sum(gt, axis=-1, dtype=jnp.int32)
+        slot = jnp.where(rank < w, rank, w)
+        hit = slot[..., :, None] == jnp.arange(w, dtype=jnp.int32)
+        sel = jnp.sum(jnp.where(hit, ia.astype(jnp.int32)[:, None], 0),
+                      axis=-2)
+    else:
+        keys = jnp.where(valid, bits >> jnp.uint32(1),
+                         jnp.uint32(0xFFFFFFFF))
+        _, sel = jax.lax.top_k(keys ^ jnp.uint32(0xFFFFFFFF), w)
+        sel = sel.astype(jnp.int32)
+    if w < width:
+        pad = jnp.zeros(sel.shape[:-1] + (width - w,), jnp.int32)
+        sel = jnp.concatenate([sel, pad], axis=-1)
+    return sel
+
+
+def _ref_tables_step(step_key, indices_p, starts_p, degrees_p, fanout_p,
+                     width, dmax):
+    """One machine-step's table: the selected slots gathered out of the
+    CSR indices, one scalar lookup per slot."""
+    n_pad = starts_p.shape[0]
+    e_pad = indices_p.shape[0]
+    bits = jax.random.bits(jax.random.fold_in(step_key, 0), (n_pad, dmax),
+                           dtype=jnp.uint32)
+    col = jnp.arange(dmax, dtype=jnp.int32)
+    sel = _ref_rank_select(bits, col[None, :] < degrees_p[:, None], width)
+    eff = jnp.minimum(degrees_p, fanout_p)
+    valid = jnp.arange(width, dtype=jnp.int32)[None, :] < eff[:, None]
+    gat = jnp.clip(starts_p[:, None] + sel, 0, e_pad - 1)
+    table = jnp.where(valid, indices_p[gat], 0).astype(jnp.int32)
+    return table, valid.astype(jnp.float32)
+
+
+def _ref_batch_step(step_key, train_p, count_p, batch_size):
+    t_pad = train_p.shape[0]
+    bits = jax.random.bits(jax.random.fold_in(step_key, 1), (t_pad,),
+                           dtype=jnp.uint32)
+    valid = jnp.arange(t_pad, dtype=jnp.int32) < count_p
+    wor = _ref_rank_select(bits, valid, batch_size)
+    rep = jax.random.randint(jax.random.fold_in(step_key, 2), (batch_size,),
+                             0, jnp.maximum(count_p, 1))
+    sel = jnp.where(count_p >= batch_size, wor[:batch_size], rep)
+    return train_p[sel].astype(jnp.int32)
+
+
+def _csr_stack(graphs, n_pad):
+    e_pad = max(max(g.num_edges for g in graphs), 1)
+    indices = np.zeros((len(graphs), e_pad), np.int32)
+    starts = np.zeros((len(graphs), n_pad), np.int32)
+    degrees = np.zeros((len(graphs), n_pad), np.int32)
+    for p, g in enumerate(graphs):
+        indices[p, :g.num_edges] = g.indices
+        starts[p, :g.num_nodes] = g.indptr[:-1]
+        degrees[p, :g.num_nodes] = g.degrees()
+    return indices, starts, degrees
+
+
+def _ref_round(graphs, n_pad, pools, fanouts, key, num_steps, width, B):
+    indices, starts, degrees = _csr_stack(graphs, n_pad)
+    t_pad = max(max(t.size for t in pools), B)
+    tn = np.zeros((len(graphs), t_pad), np.int32)
+    for p, t in enumerate(pools):
+        tn[p, :t.size] = t
+    tc = np.asarray([t.size for t in pools], np.int32)
+    dmax = max(g.max_degree() for g in graphs)
+
+    def one_machine(p, ix, st, dg, tr, ct, fo):
+        kp = jax.random.fold_in(key, p)
+
+        def one_step(s):
+            ks = jax.random.fold_in(kp, s)
+            table, mask = _ref_tables_step(ks, ix, st, dg, fo, width, dmax)
+            return table, mask, _ref_batch_step(ks, tr, ct, B)
+
+        return jax.vmap(one_step)(jnp.arange(num_steps))
+
+    return jax.vmap(one_machine)(
+        jnp.arange(len(graphs)), indices, starts, degrees, tn, tc,
+        jnp.asarray(fanouts, jnp.int32))
+
+
+def _ref_serving(graphs, n_pad, key, width):
+    indices, starts, degrees = _csr_stack(graphs, n_pad)
+    dmax = max(g.max_degree() for g in graphs)
+
+    def one_machine(p, ix, st, dg):
+        ks = jax.random.fold_in(jax.random.fold_in(key, p), 0)
+        return _ref_tables_step(ks, ix, st, dg, jnp.int32(width), width,
+                                dmax)
+
+    return jax.vmap(one_machine)(jnp.arange(len(graphs)), indices, starts,
+                                 degrees)
+
+
+def _graph_with_degrees(deg, num_nodes, rng):
+    """A CSR graph whose rows have the given degrees (distinct neighbors,
+    unsorted so the slot order is not the id order)."""
+    deg = np.asarray(deg, np.int64)
+    indptr = np.concatenate([[0], np.cumsum(deg)]).astype(np.int32)
+    indices = np.concatenate(
+        [rng.permutation(num_nodes)[:d] for d in deg]
+        or [np.zeros(0, np.int64)]).astype(np.int32)
+    return CSRGraph(indptr=indptr, indices=indices, num_nodes=len(deg))
+
+
+def _shards(max_deg, rng):
+    """Three shards of different sizes: degree-0 rows, rows below the
+    fanout, rows at ``max_deg``, and padded rows past the smaller ones."""
+    sizes = (40, 33, 25)
+    graphs = []
+    for n in sizes:
+        deg = rng.integers(0, max_deg + 1, size=n)
+        deg[:3] = (0, 1, max_deg)
+        graphs.append(_graph_with_degrees(deg, 200, rng))
+    return graphs
+
+
+@pytest.mark.parametrize("max_deg,select", [(12, "row_table"),
+                                            (130, "row_gather")])
+def test_device_round_equals_the_csr_gather(max_deg, select):
+    rng = np.random.default_rng(max_deg)
+    graphs = _shards(max_deg, rng)
+    n_pad, K, B, width = 41, 3, 6, 7
+    fanouts = [width, 4, 1]                          # narrower per machine
+    pools = [rng.choice(g.num_nodes, size=s, replace=False).astype(np.int32)
+             for g, s in zip(graphs, (20, 4, 9))]    # one pool below B
+    dcsr = build_device_csr(graphs, n_pad=n_pad, train_nodes=pools,
+                            fanouts=fanouts, t_pad_min=B)
+    assert dcsr.select == select
+    assert dcsr.nbr.shape == (3, n_pad, max_deg)
+    for seed in (0, 1, 2, 2**31 + 5):
+        key = jax.random.fold_in(jax.random.PRNGKey(seed), 3)
+        got = sample_round_device(dcsr, key, K, width, B)
+        want = _ref_round(graphs, n_pad, pools, fanouts, key, K, width, B)
+        _assert_trees_equal(got[:3], want)
+
+
+@pytest.mark.parametrize("max_deg,select", [(12, "row_table"),
+                                            (130, "row_gather")])
+@pytest.mark.parametrize("width_of", ["full", "narrow", "wide"])
+def test_device_serving_tables_equal_the_csr_gather(max_deg, select,
+                                                    width_of):
+    rng = np.random.default_rng(max_deg + 1)
+    graphs = _shards(max_deg, rng)
+    width = {"full": max_deg, "narrow": 5, "wide": max_deg + 3}[width_of]
+    dcsr = build_device_csr(graphs, n_pad=41)
+    assert dcsr.select == select
+    for seed in (0, 7, 2**31 + 5):
+        key = jax.random.PRNGKey(seed)
+        _assert_trees_equal(
+            sample_serving_tables_device(dcsr, key, width),
+            _ref_serving(graphs, 41, key, width))

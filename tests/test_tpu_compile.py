@@ -5,7 +5,9 @@ compiler must accept every program at the widths ``chip_smoke.py`` runs.
 The topology is described inside a module fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.engine import EngineConfig, RoundProgram
 from repro.core.machine import make_loss_fn
 from repro.graph.datasets import rmat_graph
+from repro.graph.sampling import DeviceCSR, sample_round_device
 from repro.kernels.edge_softmax import block_rows, gat_attention, lane_rows
 from repro.kernels.linear_scan import linear_scan_chunked
 from repro.kernels.quantize import dequantize_rows, quantize_rows
@@ -29,6 +32,8 @@ FEAT, CLASSES, HIDDEN, P, K, FANOUT, BATCH = 128, 40, 256, 8, 4, 10, 32
 # nodes per machine, cut from arxiv's 21,168 so the round compiles in
 # seconds; the node count is a scale, not a width
 NODES = 2048
+# the arxiv graph's largest degree (the sampler's key width)
+DMAX = 32
 
 
 @pytest.fixture(scope="module")
@@ -141,3 +146,31 @@ def test_bucketed_correction_step_compiles_for_v5e(one_chip):
             _sds(s, (NODES,), jnp.int32), _sds(s, (BATCH,)), place(agg))
     compiled = jax.jit(jax.grad(make_loss_fn(model))).lower(*args).compile()
     assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+# one compiled HLO instruction: its result's element type and dims, and the
+# jax primitive it was lowered from
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* .*"
+                        r'op_name="[^"]*/(\w+)"')
+
+
+def test_device_sampler_draws_tables_without_a_gather(one_chip):
+    """The round's neighbor tables are picked out of the per-row id table:
+    no gather produces a ``(P, K, NODES, FANOUT)`` table's elements (the
+    CSR gather did, one scalar HBM lookup per sampled slot)."""
+    s = one_chip
+    i32 = jnp.int32
+    dcsr = DeviceCSR(nbr=_sds(s, (P, NODES, DMAX), i32),
+                     degrees=_sds(s, (P, NODES), i32),
+                     train_nodes=_sds(s, (P, 1024), i32),
+                     train_counts=_sds(s, (P,), i32),
+                     fanouts=_sds(s, (P,), i32), dmax=DMAX)
+    compiled = jax.jit(sample_round_device, static_argnums=(2, 3, 4)).lower(
+        dcsr, _sds(s, (2,), jnp.uint32), K, FANOUT, BATCH).compile()
+    table = P * K * NODES * FANOUT
+    gathers = [m.group(0) for m in map(_HLO_INSTR.match,
+                                        compiled.as_text().splitlines())
+               if m and m.group(2) == "gather"
+               and math.prod(int(d) for d in m.group(1).split(",") if d)
+               == table]
+    assert not gathers, gathers[:3]
